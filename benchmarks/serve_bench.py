@@ -230,7 +230,7 @@ def decode_kernel_bench(*, batch, page_size, pages_per_slot, num_heads,
 
     rng = np.random.default_rng(seed)
     num_pages = batch * pages_per_slot + 1
-    pool_shape = (num_pages, page_size, num_kv_heads, head_dim)
+    pool_shape = (num_pages, num_kv_heads, page_size, head_dim)
     k_pool = jnp.asarray(rng.normal(size=pool_shape).astype(np.float32))
     v_pool = jnp.asarray(rng.normal(size=pool_shape).astype(np.float32))
     q = jnp.asarray(rng.normal(

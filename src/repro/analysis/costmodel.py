@@ -118,7 +118,7 @@ _REDUCTIONS = frozenset({
 #: host-callback primitives — each is a device<->host synchronisation
 #: point inside a jitted computation
 CALLBACK_PRIMS = frozenset({
-    "pure_callback", "io_callback", "debug_callback",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
     "host_callback_call", "infeed", "outfeed",
 })
 
@@ -145,9 +145,8 @@ def _aval_size(aval) -> int:
 
 
 def _in_avals(eqn) -> List[Any]:
-    import jax.core as jcore
-    return [v.aval for v in eqn.invars
-            if not isinstance(v, jcore.Literal)]
+    from jax.extend.core import Literal
+    return [v.aval for v in eqn.invars if not isinstance(v, Literal)]
 
 
 def _dot_general_flops(eqn) -> float:

@@ -96,9 +96,16 @@ class PagedKVCache(NamedTuple):
     which is what lets models/transformer.lm_step hoist a single
     whole-model page gather out of the layer scan instead of walking the
     table per layer (DESIGN.md §14).
+
+    Pages are head-major: one KV head's rows of one page form a
+    contiguous ``(page_size, d)`` tile, which is the block the paged
+    decode kernels stage per grid step (kernels/paged.py).  A TPU block
+    must span the full extent of, or a multiple of the (8, 128) tile in,
+    its last two dims — a token-major ``(…, page_size, h_kv, d)`` page
+    cut to one head is refused by the chip's compiler.
     """
-    k: jax.Array            # (num_pages, page_size, h_kv, d) pool
-    v: jax.Array            # (num_pages, page_size, h_kv, d) pool
+    k: jax.Array            # (num_pages, h_kv, page_size, d) pool
+    v: jax.Array            # (num_pages, h_kv, page_size, d) pool
     block_tables: jax.Array  # (b, pages_per_slot) int32
     length: jax.Array       # (b,) int32 per-slot cursors
 
@@ -110,7 +117,7 @@ def init_paged_kv_cache(batch: int, max_len: int, num_kv_heads: int,
     pages_per_slot = -(-max_len // page_size)
     if num_pages is None:
         num_pages = batch * pages_per_slot + 1      # +1: trash page 0
-    shape = (num_pages, page_size, num_kv_heads, head_dim)
+    shape = (num_pages, num_kv_heads, page_size, head_dim)
     return PagedKVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
                         jnp.zeros((batch, pages_per_slot), jnp.int32),
                         jnp.zeros((batch,), jnp.int32))
@@ -242,13 +249,14 @@ def apply_attention(
     if isinstance(cache, PagedKVCache):
         # scatter new k/v into the block-table pages at the cursor(s);
         # the 'paged' backend gathers the pages back per row
-        ps = cache.k.shape[1]
+        ps = cache.k.shape[2]
         pos = cache.length[:, None] + jnp.arange(n_q)[None, :]     # (b, n_q)
         rows = jnp.arange(b)[:, None]
         pages = cache.block_tables[rows, pos // ps]                # (b, n_q)
         offs = pos % ps
-        k_pool = cache.k.at[pages, offs].set(k.astype(cache.k.dtype))
-        v_pool = cache.v.at[pages, offs].set(v.astype(cache.v.dtype))
+        # head-major pool: [pages, :, offs] addresses (b, n_q, h_kv, d)
+        k_pool = cache.k.at[pages, :, offs].set(k.astype(cache.k.dtype))
+        v_pool = cache.v.at[pages, :, offs].set(v.astype(cache.v.dtype))
         new_cache = PagedKVCache(k_pool, v_pool, cache.block_tables,
                                  cache.length + n_q)
         k, v = k_pool.astype(cdt), v_pool.astype(cdt)

@@ -126,7 +126,7 @@ class Structural:
 @dataclasses.dataclass(frozen=True)
 class PagedLayout:
     """Block-table layout for the ``paged`` backend.  ``k``/``v`` arrive as
-    page pools (num_pages, page_size, h_kv, d); ``block_tables``
+    head-major page pools (num_pages, h_kv, page_size, d); ``block_tables``
     (b, pages_per_slot) int32 maps each batch row's logical page index to a
     physical page.  Validity is expressed through the ordinary mask path
     (the gathered view is logically contiguous per row)."""
@@ -495,7 +495,7 @@ def execute_plan(plan: ExecutionPlan, q, k, v, *,
     ``mask`` is only legal for mask-consuming backends; mask-free backends
     take ``structural`` instead.  Mixing the two is a dispatch bug and
     fails loudly.  For the ``paged`` backend, k/v are page pools
-    (num_pages, page_size, h_kv, d) and ``paged`` carries the block tables.
+    (num_pages, h_kv, page_size, d) and ``paged`` carries the block tables.
     """
     mech = get_mechanism(plan.mechanism)
     fn = mech.backends.get(plan.backend)
@@ -642,7 +642,7 @@ def _inhibitor_pallas(q, k, v, *, mask=None, params, structural=None):
 def _gather_pages(k_pool, v_pool, paged: PagedLayout):
     """Gather per-row contiguous KV views out of the page pools.
 
-    k_pool/v_pool: (num_pages, page_size, h_kv, d); block tables (b, P).
+    k_pool/v_pool: (num_pages, h_kv, page_size, d); block tables (b, P).
     Returns (b, P*page_size, h_kv, d) views — one gather per call, fused by
     XLA into the downstream reads.  Unmapped table entries point at the
     reserved trash page 0; those rows sit beyond the valid-length mask.
@@ -653,10 +653,12 @@ def _gather_pages(k_pool, v_pool, paged: PagedLayout):
     TPU single-query decode the planner selects ``paged_pallas`` instead,
     which never materializes this view at all (DESIGN.md §10).
     """
-    kt = k_pool[paged.block_tables]            # (b, P, ps, h_kv, d)
-    vt = v_pool[paged.block_tables]
-    b, npg, ps, hk, d = kt.shape
-    return (kt.reshape(b, npg * ps, hk, d), vt.reshape(b, npg * ps, hk, d))
+    def view(pool):
+        t = pool[paged.block_tables]              # (b, P, h_kv, ps, d)
+        b, npg, hk, ps, d = t.shape
+        return t.transpose(0, 1, 3, 2, 4).reshape(b, npg * ps, hk, d)
+
+    return view(k_pool), view(v_pool)
 
 
 def _paged_lengths(q, s: Structural):

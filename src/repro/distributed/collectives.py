@@ -21,7 +21,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def compressed_grad_sync(grads, mesh: Mesh, *, compress_pod: bool = True):
@@ -49,8 +48,8 @@ def compressed_grad_sync(grads, mesh: Mesh, *, compress_pod: bool = True):
                     gl = jax.lax.pmean(gl, "pod")
             return gl
 
-        return shard_map(inner, mesh=mesh, in_specs=P(), out_specs=P(),
-                         check_rep=False)(g)
+        return jax.shard_map(inner, mesh=mesh, in_specs=P(), out_specs=P(),
+                             check_vma=False)(g)
 
     return jax.tree.map(sync_one, grads)
 
@@ -89,9 +88,9 @@ def allgather_matmul(x: jax.Array, w: jax.Array, mesh: Mesh, *,
         acc, _ = jax.lax.fori_loop(0, deg, body, (acc0, xl))
         return acc
 
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(None, axis), P(None, axis)),
         out_specs=P(None, axis),
-        check_rep=False,
+        check_vma=False,
     )(x, w)

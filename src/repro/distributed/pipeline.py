@@ -24,7 +24,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def bubble_fraction(num_stages: int, num_microbatches: int) -> float:
@@ -32,7 +31,9 @@ def bubble_fraction(num_stages: int, num_microbatches: int) -> float:
 
 
 def make_pp_mesh(num_stages: int, data: int = 1, model: int = 1) -> Mesh:
-    return jax.make_mesh((num_stages, data, model), ("pipe", "data", "model"))
+    from repro.launch.mesh import auto_mesh
+
+    return auto_mesh((num_stages, data, model), ("pipe", "data", "model"))
 
 
 def pipeline_apply(
@@ -94,9 +95,9 @@ def pipeline_apply(
         outputs = jax.lax.psum(outputs, "pipe")
         return outputs
 
-    return shard_map(
+    return jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P("pipe"), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, x)

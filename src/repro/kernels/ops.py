@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import logging
 import time
 from typing import Callable, Dict, Optional, Sequence, Tuple
 
@@ -58,12 +59,13 @@ from repro.kernels.paged import (paged_flash_attention_fwd,
                                  paged_flash_inhibitor_fwd)
 from repro.kernels.rwkv6 import wkv6_chunked
 
+log = logging.getLogger("repro.kernels")
+
 
 def _host_platform() -> str:
-    try:
-        return jax.devices()[0].platform
-    except RuntimeError:
-        return "cpu"
+    """The default device's platform.  A failed probe raises: reading it
+    as "cpu" would silently put a chip host on the interpret path."""
+    return jax.devices()[0].platform
 
 
 def _on_tpu() -> bool:
@@ -237,15 +239,23 @@ class KernelRegistry:
             ranked = self._ranked(family, shape_key, candidates)
             skip_inf = any(p != float("inf") for _, p in ranked)
             best_t = float("inf")
+            errors = []
             for cand, prior in ranked:
                 if skip_inf and prior == float("inf"):
                     continue
                 try:
                     t = timer(cand)
-                except Exception:  # noqa: BLE001 — an invalid candidate
-                    continue       # (VMEM overflow, …) just drops out
+                except Exception as e:  # noqa: BLE001 — an invalid
+                    # candidate (VMEM overflow, …) drops out, on record
+                    log.warning("autotune %s %s: candidate %s dropped: "
+                                "%s: %s", family, shape_key, cand,
+                                type(e).__name__, e)
+                    errors.append(e)
+                    continue
                 if t < best_t:
                     best_t, choice = t, cand
+            if best_t == float("inf") and errors:
+                raise errors[0]
         self.tuned[key] = choice
         self._record(family, key, choice, source)
         return choice
@@ -257,7 +267,10 @@ class KernelRegistry:
         try:
             from repro.analysis.costmodel import rank_kernel_candidates
             ranked = rank_kernel_candidates(family, shape_key, candidates)
-        except Exception:  # noqa: BLE001 — priors must never block tuning
+        except Exception as e:  # noqa: BLE001 — priors must never block
+            log.warning("autotune %s %s: cost-model priors failed (%s: "
+                        "%s); timing in declared order", family,
+                        shape_key, type(e).__name__, e)
             ranked = [(c, float("inf")) for c in candidates]
         self.priors[key] = ranked
         return ranked
@@ -421,8 +434,9 @@ def flash_attention_cached(q, k, v, q_offset, kv_valid_len, *,
 
 def _paged_choice(family_key, q, k_pool, block_tables,
                   override: Optional[KernelChoice], runner):
-    shape_key = (family_key, block_tables.shape[1], k_pool.shape[1],
-                 q.shape[2], k_pool.shape[2], q.shape[3])
+    # (family, pages, page_size, heads, kv_heads, d) — head-major pools
+    shape_key = (family_key, block_tables.shape[1], k_pool.shape[2],
+                 q.shape[2], k_pool.shape[1], q.shape[3])
     timer = None
     if (override is None or override.empty) and _concrete(
             q, k_pool, block_tables):

@@ -24,6 +24,11 @@ Grid layout:
     separate BlockSpec'd inputs (pages are not contiguous in the pool, so
     one wider block cannot cover them); the kernel loops over the staged
     refs.
+  * pools are head-major, (num_pages, h_kv, page_size, d): the staged
+    block ``(1, 1, page_size, d)`` is one KV head's contiguous tile of a
+    page, so its last two dims are (page_size, full d) — the Mosaic
+    tiling rule (multiples of (8, 128) or the full extent) holds for any
+    page_size that is a multiple of 8.
   * GQA: all ``group = heads / kv_heads`` query heads sharing a KV head
     are processed against one staged page (same staging as
     :mod:`repro.kernels.inhibitor`).
@@ -76,7 +81,7 @@ def _decode_layout(q, k_pool, block_tables, lengths):
         raise ValueError(f"paged decode kernels are single-query (n_q=1); "
                          f"got n_q={n_q} — prefill goes through the gather "
                          f"path")
-    num_pages, page_size, kv_heads, dk = k_pool.shape
+    num_pages, kv_heads, page_size, dk = k_pool.shape
     assert d == dk and heads % kv_heads == 0
     group = heads // kv_heads
     if block_tables.shape[0] != batch or lengths.shape != (batch,):
@@ -100,13 +105,13 @@ def _page_specs(pps: int, page_size: int, kv_heads: int, d: int,
     def page_index(bh, j, tables, lengths, i):
         del lengths
         logical = jnp.minimum(j * pps + i, table_width - 1)
-        return (tables[bh // kv_heads, logical], 0, bh % kv_heads, 0)
+        return (tables[bh // kv_heads, logical], bh % kv_heads, 0, 0)
 
     specs = []
     for i in range(pps):
         idx = functools.partial(page_index, i=i)
-        specs.append(pl.BlockSpec((1, page_size, 1, d), idx))  # k page i
-        specs.append(pl.BlockSpec((1, page_size, 1, d), idx))  # v page i
+        specs.append(pl.BlockSpec((1, 1, page_size, d), idx))  # k page i
+        specs.append(pl.BlockSpec((1, 1, page_size, d), idx))  # v page i
     return specs
 
 
@@ -143,8 +148,8 @@ def _paged_inhibitor_kernel(
     q_pos = valid - 1
 
     def process_page(i, acc, cnt):
-        ks = kv_refs[2 * i][0, :, 0, :].astype(jnp.float32)   # (ps, d)
-        vs = kv_refs[2 * i + 1][0, :, 0, :].astype(jnp.float32)
+        ks = kv_refs[2 * i][0, 0].astype(jnp.float32)         # (ps, d)
+        vs = kv_refs[2 * i + 1][0, 0].astype(jnp.float32)
 
         # ---- scores: Z = relu(Σ_d |q − k| / γ − α)  (eq. 5 + shift) ----
         diff = jnp.abs(q[:, None, :] - ks[None, :, :])        # (g, ps, d)
@@ -178,10 +183,12 @@ def _paged_inhibitor_kernel(
                             * mf[0][None, :, None], axis=1)
             part = 0.5 * (col_v - row_z[:, None] + cross)
 
-        return acc + part, cnt + jnp.sum(mf)
+        return acc + part, cnt + jnp.sum(mf, keepdims=True)
 
+    # the key count lives in a (1, 1) VMEM tile and is read and written
+    # as an array: Mosaic cannot store a scalar to VMEM
     def do_step():
-        acc, cnt = acc_ref[...], cnt_ref[0, 0]
+        acc, cnt = acc_ref[...], cnt_ref[...]
         for i in range(pps):
             acc, cnt = process_page(i, acc, cnt)
         return acc, cnt
@@ -189,21 +196,21 @@ def _paged_inhibitor_kernel(
     # skip steps wholly past the cursor (their table entries are trash)
     acc, cnt = jax.lax.cond(
         j * pps * page_size < valid, do_step,
-        lambda: (acc_ref[...], cnt_ref[0, 0]))
+        lambda: (acc_ref[...], cnt_ref[...]))
     acc_ref[...] = acc
-    cnt_ref[0, 0] = cnt
+    cnt_ref[...] = cnt
 
     @pl.when(j == n_steps - 1)
     def _finalize():
         out = acc_ref[...]
         if normalize:
-            out = out / jnp.maximum(cnt_ref[0, 0], 1.0)
+            out = out / jnp.maximum(cnt_ref[...], 1.0)
         o_ref[0] = out.astype(o_ref.dtype)
 
 
 def paged_flash_inhibitor_fwd(
     q: jax.Array,               # (batch, 1, heads, d)
-    k_pool: jax.Array,          # (num_pages, page_size, kv_heads, d)
+    k_pool: jax.Array,          # (num_pages, kv_heads, page_size, d)
     v_pool: jax.Array,
     block_tables: jax.Array,    # (batch, P) int32
     lengths: jax.Array,         # (batch,) int32 per-row cursors
@@ -278,8 +285,8 @@ def _paged_attention_kernel(
     q_pos = valid - 1
 
     def process_page(i, acc, m_prev, l_prev):
-        ks = kv_refs[2 * i][0, :, 0, :].astype(jnp.float32)   # (ps, d)
-        vs = kv_refs[2 * i + 1][0, :, 0, :].astype(jnp.float32)
+        ks = kv_refs[2 * i][0, 0].astype(jnp.float32)         # (ps, d)
+        vs = kv_refs[2 * i + 1][0, 0].astype(jnp.float32)
         k_pos = ((j * pps + i) * page_size
                  + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1))
         m_blk = k_pos < valid

@@ -56,12 +56,14 @@ def wkv6_ref(r, k, v, w, u, state=None):
 
 
 def _gather_paged(k_pool, v_pool, block_tables):
-    """(num_pages, ps, h_kv, d) pools + (b, P) tables -> contiguous
+    """(num_pages, h_kv, ps, d) pools + (b, P) tables -> contiguous
     (b, P*ps, h_kv, d) views — the gather the paged kernels replace."""
-    kt = k_pool[block_tables]
-    vt = v_pool[block_tables]
-    b, npg, ps, hk, d = kt.shape
-    return (kt.reshape(b, npg * ps, hk, d), vt.reshape(b, npg * ps, hk, d))
+    def view(pool):
+        t = pool[block_tables]                    # (b, P, h_kv, ps, d)
+        b, npg, hk, ps, d = t.shape
+        return t.transpose(0, 1, 3, 2, 4).reshape(b, npg * ps, hk, d)
+
+    return view(k_pool), view(v_pool)
 
 
 def _decode_mask(n_k: int, lengths, window: Optional[int]):

@@ -1,8 +1,12 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
-# The two lines above MUST run before any other import (jax locks the
-# device count at first backend init) — see the multi-pod dry-run spec.
+# a CPU-only compile tool: pin the platform so neither this process nor
+# its per-cell children (which inherit the environment) ever takes a chip
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any other import (jax locks the device
+# count and platform at first backend init) — see the multi-pod dry-run
+# spec.
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 

@@ -3,11 +3,13 @@ model.
 
     python -m repro.launch.serve --arch smollm-135m --requests 16
 
-Loads params from --ckpt-dir if given (falls back to random init), then
-drives the engine with synthetic ragged prompt traffic and reports
-throughput plus the paged-cache accounting (prefill compile count,
-page-pool high-water mark) and the shared-prefix cache counters
-(hit tokens, CoW forks, evictions).  ``--allocator contiguous`` selects
+Serves the model at its published widths; ``--reduced`` swaps in the
+per-family CPU-scale config (2 layers, d_model 64).  Loads params from
+--ckpt-dir if given (falls back to random init), then drives the engine
+with synthetic ragged prompt traffic and reports throughput plus the
+paged-cache accounting (prefill compile count, page-pool high-water
+mark) and the shared-prefix cache counters (hit tokens, CoW forks,
+evictions).  ``--allocator contiguous`` selects
 the dense per-slot baseline; the default is the paged block-table cache
 with the radix prefix index on.  ``--shared-prefix N`` makes every
 synthetic prompt share an N-token prefix (system-prompt traffic) so the
@@ -40,12 +42,38 @@ import jax
 import numpy as np
 
 
+def build_engine(name: str, ecfg, *, reduced: bool = False, seed: int = 0,
+                 ckpt_dir=None):
+    """Model ``name`` (``arch`` or ``arch@mechanism``) behind an
+    :class:`~repro.serve.engine.Engine` configured by ``ecfg``: params
+    from ``ckpt_dir`` when given, else a random init from ``seed``.  The
+    engine owns state layout: per-slot cursors always (ragged continuous
+    batching), paged block tables when the family supports it."""
+    from repro.configs import get_config
+    from repro.models.registry import get_model
+    from repro.nn.module import unbox
+    from repro.serve.engine import Engine
+
+    cfg = get_config(name)
+    if reduced:
+        cfg = cfg.reduced()
+    api = get_model(cfg)
+    params = unbox(api.init(jax.random.PRNGKey(seed)))
+    if ckpt_dir:
+        from repro.checkpoint import restore
+        params, _ = restore(ckpt_dir, (params, None))[0]
+    return Engine(api, params, ecfg, seed=seed)
+
+
 def main(argv=None):
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
     ap.add_argument("--attention", default=None)
-    ap.add_argument("--reduced", action="store_true", default=True)
-    ap.add_argument("--full", dest="reduced", action="store_false")
+    ap.add_argument("--reduced", action="store_true", default=False,
+                    help="use the reduced per-family config (CPU scale)")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--max-batch", type=int, default=4)
@@ -92,10 +120,7 @@ def main(argv=None):
     logging.basicConfig(level=logging.INFO)
     log = logging.getLogger("repro.launch.serve")
 
-    from repro.configs import get_config
-    from repro.models.registry import get_model
-    from repro.nn.module import unbox
-    from repro.serve.engine import Engine, EngineConfig, Request
+    from repro.serve.engine import EngineConfig, Request
     from repro.serve.telemetry import TelemetryConfig, write_trace
 
     # telemetry is opt-in: full span tracing when a trace sink is given,
@@ -110,31 +135,22 @@ def main(argv=None):
         telemetry = TelemetryConfig(trace=False)
 
     name = args.arch if not args.attention else f"{args.arch}@{args.attention}"
-    cfg = get_config(name)
-    if args.reduced:
-        cfg = cfg.reduced()
-    api = get_model(cfg)
-    params = unbox(api.init(jax.random.PRNGKey(args.seed)))
-    if args.ckpt_dir:
-        from repro.checkpoint import restore
-        (params, _), step = restore(args.ckpt_dir, (params, None))[0], None
-
-    # the engine owns state layout: per-slot cursors always (ragged
-    # continuous batching), paged block tables when the family supports it
-    eng = Engine(api, params,
-                 EngineConfig(max_batch=args.max_batch,
-                              max_len=args.max_len,
-                              allocator=args.allocator,
-                              page_size=args.page_size,
-                              num_pages=args.num_pages,
-                              prefill_chunk=args.prefill_chunk,
-                              tick_budget=args.tick_budget,
-                              prefix_cache=args.prefix_cache,
-                              scheduler=args.scheduler,
-                              greedy=not args.sample,
-                              temperature=args.temperature,
-                              telemetry=telemetry),
-                 seed=args.seed)
+    eng = build_engine(
+        name,
+        EngineConfig(max_batch=args.max_batch,
+                     max_len=args.max_len,
+                     allocator=args.allocator,
+                     page_size=args.page_size,
+                     num_pages=args.num_pages,
+                     prefill_chunk=args.prefill_chunk,
+                     tick_budget=args.tick_budget,
+                     prefix_cache=args.prefix_cache,
+                     scheduler=args.scheduler,
+                     greedy=not args.sample,
+                     temperature=args.temperature,
+                     telemetry=telemetry),
+        reduced=args.reduced, seed=args.seed, ckpt_dir=args.ckpt_dir)
+    cfg = eng.api.cfg
 
     rng = np.random.default_rng(args.seed)
     plen = max(1, min(args.prompt_len, args.max_len - 1))

@@ -19,7 +19,7 @@ import jax
 import numpy as np
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
     ap.add_argument("--attention", default=None)
@@ -37,10 +37,12 @@ def main(argv=None):
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--max-restarts", type=int, default=2)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
-    logging.basicConfig(level=logging.INFO,
-                        format="%(asctime)s %(name)s %(message)s")
+
+def run(args: argparse.Namespace) -> dict:
+    """Train as the parsed arguments say; returns the loop's result
+    (``params``, ``opt_state``, ``history``)."""
     log = logging.getLogger("repro.launch.train")
 
     from repro.checkpoint import CheckpointConfig
@@ -79,7 +81,7 @@ def main(argv=None):
 
     result = {}
 
-    def run(attempt):
+    def attempt_once(attempt):
         log.info("attempt %d: training %s for %d steps on %dx%d mesh",
                  attempt, cfg.name, args.steps, dp, mp)
         if dp * mp > 1:
@@ -89,11 +91,23 @@ def main(argv=None):
         else:
             result.update(train(api, opt_cfg, train_cfg, batch_fn))
 
-    run_supervised(run, SupervisorConfig(max_restarts=args.max_restarts))
+    run_supervised(attempt_once,
+                   SupervisorConfig(max_restarts=args.max_restarts))
     hist = result["history"]
     if hist:
         log.info("final loss %.4f (first %.4f)", hist[-1]["loss"],
                  hist[0]["loss"])
+    return result
+
+
+def main(argv=None):
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    args = parse_args(argv)
+    logging.basicConfig(level=logging.INFO,
+                        format="%(asctime)s %(name)s %(message)s")
+    run(args)
     return 0
 
 

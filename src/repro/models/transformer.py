@@ -399,11 +399,11 @@ def fused_gather_applies(cfg: ModelConfig, kv, n_q: int) -> bool:
     a = cfg.attention
     if a.backend is not None or a.use_kernel:
         return False
-    ps = kv.k.shape[2]
+    ps = kv.k.shape[3]
     shapes = AttnShapes(
         batch=kv.block_tables.shape[1], n_q=n_q,
         n_k=kv.block_tables.shape[2] * ps,
-        num_heads=a.num_heads, num_kv_heads=kv.k.shape[3],
+        num_heads=a.num_heads, num_kv_heads=kv.k.shape[2],
         head_dim=a.head_dim, dtype=cfg.cdtype,
         has_explicit_mask=False, is_cross=False, has_cache=True,
         scalar_cursor=False, paged=True)
@@ -415,7 +415,7 @@ def fused_gather_applies(cfg: ModelConfig, kv, n_q: int) -> bool:
 
 
 def _gather_paged_view(kv: PagedKVCache) -> KVCache:
-    """ONE whole-model page gather: stacked pools (L, pages, ps, hk, d)
+    """ONE whole-model page gather: stacked pools (L, pages, hk, ps, d)
     → contiguous logical view (L, b, P·ps, hk, d) for every layer.
 
     ``init_states`` broadcasts a single cache over layers and the engine
@@ -426,12 +426,14 @@ def _gather_paged_view(kv: PagedKVCache) -> KVCache:
     no (b, P, ps, …) → (b, P·ps, …) reshape of the gathered data is ever
     materialized."""
     tables = kv.block_tables[0]                       # (b, P), layer-shared
-    ps = kv.k.shape[2]
+    ps = kv.k.shape[3]
     page_idx = jnp.repeat(tables, ps, axis=1)         # (b, N): tables[b, j//ps]
     off_idx = jnp.tile(jnp.arange(ps, dtype=tables.dtype),
                        tables.shape[1])[None]         # (1, N): j % ps
-    kc = kv.k[:, page_idx, off_idx]                   # (L, b, N, hk, d)
-    vc = kv.v[:, page_idx, off_idx]
+    # the page and offset indices straddle the head axis, so numpy
+    # indexing puts the index dims first: (b, N, L, hk, d)
+    kc = kv.k[:, page_idx, :, off_idx].transpose(2, 0, 1, 3, 4)
+    vc = kv.v[:, page_idx, :, off_idx].transpose(2, 0, 1, 3, 4)
     return KVCache(kc, vc, kv.length)
 
 
@@ -442,13 +444,16 @@ def _scatter_paged_rows(kv: PagedKVCache, view: KVCache,
     inactive slots land on trash page 0 exactly as the per-layer scatter
     did — duplicate trash-page writes are don't-care by design."""
     tables = kv.block_tables[0]
-    ps = kv.k.shape[2]
+    ps = kv.k.shape[3]
     rows = jnp.arange(tables.shape[0])[:, None]                    # (b, 1)
     pos = kv.length[0][:, None] + jnp.arange(n_q)[None]            # (b, t)
     pages = tables[rows, pos // ps]
     offs = pos % ps
-    k_pool = kv.k.at[:, pages, offs].set(view.k[:, rows, pos])
-    v_pool = kv.v.at[:, pages, offs].set(view.v[:, rows, pos])
+    # [:, pages, :, offs] addresses (b, t, L, hk, d) — see the gather
+    k_pool = kv.k.at[:, pages, :, offs].set(
+        view.k[:, rows, pos].transpose(1, 2, 0, 3, 4))
+    v_pool = kv.v.at[:, pages, :, offs].set(
+        view.v[:, rows, pos].transpose(1, 2, 0, 3, 4))
     return PagedKVCache(k_pool, v_pool, kv.block_tables, view.length)
 
 

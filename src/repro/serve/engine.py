@@ -218,7 +218,7 @@ def decode_table_width(longest: int, *, page_size: int,
 @functools.partial(jax.jit, donate_argnums=(0, 1))
 def _jit_pool_page_copy(k_pool, v_pool, old, new):
     """Copy physical page ``old`` -> ``new`` in the stacked
-    (L, num_pages, page_size, h_kv, d) K/V pools.  The pools are donated,
+    (L, num_pages, h_kv, page_size, d) K/V pools.  The pools are donated,
     so XLA aliases the buffers and the copy is O(page), not a fresh
     pool-sized allocation (the CoW fork path — Engine._copy_page)."""
     return (k_pool.at[:, new].set(k_pool[:, old]),

@@ -11,7 +11,7 @@ Two allocators back those slots:
 
 ``PagedAllocator`` (block tables)
     KV rows live in a shared pool of fixed-size pages
-    (layers, num_pages, page_size, kv_heads, head_dim).  Each slot holds a
+    (layers, num_pages, kv_heads, page_size, head_dim).  Each slot holds a
     block table mapping logical page index -> physical page; pages are
     handed out from a free list on demand as a request's cursor grows and
     reclaimed in O(pages-held) when the slot is released (free-list push,

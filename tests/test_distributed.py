@@ -75,6 +75,7 @@ def test_sharded_train_step_matches_single_device():
         from repro.configs import get_config
         from repro.distributed.sharding import use_mesh
         from repro.launch import shardings as shlib
+        from repro.launch.mesh import make_mesh
         from repro.models.registry import get_model
         from repro.optim import AdamWConfig, init_adamw
         from repro.train.step import init_train_state, make_train_step
@@ -96,7 +97,7 @@ def test_sharded_train_step_matches_single_device():
         step = make_train_step(api, opt_cfg)
         p1, o1, m1 = jax.jit(step)(params, opt_state, batch)
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh(2, 2, pods=2)
         with use_mesh(mesh):
             boxed = jax.eval_shape(api.init, jax.random.PRNGKey(0))
             _, psh = shlib.params_shardings(boxed, mesh)
@@ -121,15 +122,40 @@ def test_sharded_train_step_matches_single_device():
     """)
 
 
-def test_dryrun_single_cell_multipod():
+def test_dryrun_single_cell_multipod(tmp_path):
     """A small arch lowers+compiles on the 2x16x16 multi-pod mesh."""
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch",
          "smollm-135m", "--shape", "decode_32k", "--multi-pod",
-         "--out-dir", os.path.join(_ROOT, "experiments", "dryrun_test")],
+         "--out-dir", str(tmp_path)],
         env={**env, "PYTHONPATH": os.path.join(_ROOT, "src")},
         capture_output=True, text=True, timeout=900, cwd=_ROOT)
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr}"
     assert "[OK]" in r.stdout
+
+
+def test_launch_train_shards_state_over_the_mesh():
+    """launch.train's data+model-parallel path places params across the
+    mesh (not all on device 0) and matches the single-device run."""
+    _run("""
+        from repro.launch import train as train_cli
+
+        def run(dp, mp):
+            return train_cli.run(train_cli.parse_args([
+                "--arch", "smollm-135m", "--steps", "2", "--batch", "8",
+                "--seq", "16", "--data-parallel", str(dp),
+                "--model-parallel", str(mp), "--max-restarts", "0"]))
+
+        one, mesh8 = run(1, 1), run(4, 2)
+        leaves = jax.tree.leaves(mesh8["params"])
+        devs = {d for x in leaves for d in x.sharding.device_set}
+        assert len(devs) == 8, devs
+        assert any(not x.sharding.is_fully_replicated for x in leaves)
+        for a, b in zip(one["history"], mesh8["history"]):
+            assert abs(a["loss"] - b["loss"]) < 1e-3, (a, b)
+        for a, b in zip(jax.tree.leaves(one["params"]), leaves):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-3, atol=2e-3)
+    """)

@@ -28,11 +28,15 @@ TOL = dict(rtol=1e-4, atol=1e-5)
 
 def _pool(rng, *, batch, pages_per_slot, page_size, kv_heads, d,
           lengths):
-    """A ragged paged pool: per-row non-contiguous physical pages, trash
-    page 0 for every unmapped table entry (the engine's layout)."""
+    """A ragged head-major paged pool (num_pages, kv_heads, page_size, d):
+    per-row non-contiguous physical pages, trash page 0 for every
+    unmapped table entry (the engine's layout)."""
     num_pages = batch * pages_per_slot + 1
+    # drawn token-major, stored head-major: the same KV content per
+    # (page, offset, head) as a token-major pool drawn from this rng
     kp = rng.normal(size=(num_pages, page_size, kv_heads, d))
     vp = rng.normal(size=(num_pages, page_size, kv_heads, d))
+    kp, vp = kp.transpose(0, 2, 1, 3), vp.transpose(0, 2, 1, 3)
     perm = rng.permutation(np.arange(1, num_pages))
     tables = np.zeros((batch, pages_per_slot), np.int32)
     nxt = 0
@@ -143,8 +147,8 @@ def test_trash_page_garbage_cannot_reach_attendable_positions(rng):
         last_page = int(np.asarray(tables)[b, used - 1])
         tail = ln - (used - 1) * 8
         if tail < 8:
-            kp_bad = kp_bad.at[last_page, tail:].set(1e9)
-            vp_bad = vp_bad.at[last_page, tail:].set(-1e9)
+            kp_bad = kp_bad.at[last_page, :, tail:].set(1e9)
+            vp_bad = vp_bad.at[last_page, :, tail:].set(-1e9)
 
     for fwd, kw in ((paged_flash_inhibitor_fwd, dict(signed=True)),
                     (paged_flash_attention_fwd, {})):
@@ -279,6 +283,7 @@ def test_shared_pages_across_rows_read_identically(rng):
     num_pages = batch * pages_per_slot + 1
     kp = rng.normal(size=(num_pages, ps, kv_heads, d)).astype(np.float32)
     vp = rng.normal(size=(num_pages, ps, kv_heads, d)).astype(np.float32)
+    kp, vp = kp.transpose(0, 2, 1, 3), vp.transpose(0, 2, 1, 3)
     lengths = np.asarray([21, 18, 13], np.int32)
     tables = np.zeros((batch, pages_per_slot), np.int32)
     tables[0, :3] = [1, 2, 3]       # rows 0/1 share physical pages 1, 2
@@ -291,8 +296,8 @@ def test_shared_pages_across_rows_read_identically(rng):
     # dense oracle: gather each row's logical view and run the reference
     def dense_view(pool):
         arr = np.asarray(pool)
-        out = np.stack([arr[tables[b]].reshape(-1, kv_heads, d)
-                        for b in range(batch)])
+        out = np.stack([arr[tables[b]].transpose(0, 2, 1, 3)
+                        .reshape(-1, kv_heads, d) for b in range(batch)])
         return jnp.asarray(out)
 
     kd, vd = dense_view(kp), dense_view(vp)
@@ -377,3 +382,44 @@ def test_choose_records_decision_provenance():
     dt = rt.decisions[("paged",) + key]
     assert dt["source"] == "timed" and dt["native"] is True
     assert ("paged",) + key in rt.priors
+
+
+def test_autotune_logs_dropped_candidates_and_raises_when_all_fail(caplog):
+    """A candidate that raises during timing drops out with its error on
+    record; when every candidate fails, the first error propagates
+    instead of silently pinning a default that was never shown to run."""
+    r = kops.KernelRegistry()
+    r._platform = "tpu"
+    key = ("probe", 4, 16, 9, 3, 64)
+
+    def flaky(c):
+        if c.pages_per_step != 2:
+            raise RuntimeError(f"vmem overflow pps={c.pages_per_step}")
+        return 1.0
+
+    with caplog.at_level("WARNING", logger="repro.kernels"):
+        got = r.choose("paged", key, timer=flaky)
+    assert got.pages_per_step == 2
+    dropped = [m for m in caplog.messages if "dropped" in m]
+    assert len(dropped) == len(kops.CANDIDATES["paged"]) - 1
+    assert all("vmem overflow" in m for m in dropped)
+
+    def broken(c):
+        raise RuntimeError(f"lowering failed pps={c.pages_per_step}")
+
+    fresh = kops.KernelRegistry()
+    fresh._platform = "tpu"
+    with pytest.raises(RuntimeError, match="lowering failed"):
+        fresh.choose("paged", key, timer=broken)
+    assert ("paged",) + key not in fresh.tuned
+
+
+def test_host_platform_probe_errors_propagate(monkeypatch):
+    """A failed device probe is an error, not a quiet "cpu": reading it
+    as cpu would put a chip host's kernels on the interpret path."""
+    def no_backend():
+        raise RuntimeError("backend init failed")
+
+    monkeypatch.setattr(kops.jax, "devices", no_backend)
+    with pytest.raises(RuntimeError, match="backend init failed"):
+        _ = kops.KernelRegistry().platform

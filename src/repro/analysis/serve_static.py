@@ -479,14 +479,15 @@ def audit_engine_file(path: Optional[str] = None) -> Dict[str, Any]:
     return sync_summary(audit_sync_sites(src, rel))
 
 
-#: the telemetry emit path: every Tracer/Histogram/MetricsRegistry
-#: method the engine may call per tick / per event while serving.  The
-#: audit closes the call graph from these roots over serve/telemetry.py
-#: — export/validation/CLI code is deliberately outside (it runs when a
-#: trace is written, not while serving).
+#: the telemetry emit path: the tick-phase span helper and every
+#: Tracer/Histogram/MetricsRegistry method the engine may call per tick /
+#: per event while serving.  The audit closes the call graph from these
+#: roots over serve/telemetry.py — export/validation/CLI code is
+#: deliberately outside (it runs when a trace is written, not while
+#: serving).
 TELEMETRY_SYNC_ROOTS = (
-    "_emit", "now", "begin", "end", "instant", "complete", "counter",
-    "set_meta", "set_thread_name", "request_submit", "request_admitted",
+    "phase", "_emit", "now", "begin", "end", "instant", "complete",
+    "counter", "set_meta", "request_submit", "request_admitted",
     "request_chunks", "request_paused", "request_resumed",
     "request_restaged", "request_decode", "request_finish",
     "request_cancel", "record", "histogram",

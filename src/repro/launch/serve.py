@@ -125,9 +125,10 @@ def main(argv=None):
 
     # telemetry is opt-in: full span tracing when a trace sink is given,
     # flight-recorder-only (bounded ring, no event list) under
-    # --log-json, and entirely absent otherwise — the engine hooks are
+    # --log-json, and no Tracer otherwise — the engine hooks are
     # `if tel is None` guarded, so off means zero events and zero
-    # allocation (proven by the analyzer's telemetry sync audit).
+    # allocation (proven by the analyzer's telemetry sync audit); the
+    # tick phases' profiler annotations are entered either way.
     telemetry = None
     if args.trace_out:
         telemetry = TelemetryConfig(trace=True)

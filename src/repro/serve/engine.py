@@ -74,7 +74,8 @@ from repro.models.registry import ModelApi
 from repro.serve.kvcache import PagedAllocator, SlotAllocator
 from repro.serve.prefix import PrefixIndex
 from repro.serve.scheduler import make_scheduler
-from repro.serve.telemetry import MetricsRegistry, dump_flight, make_tracer
+from repro.serve.telemetry import (MetricsRegistry, dump_flight,
+                                   make_tracer, phase)
 
 log = logging.getLogger("repro.serve")
 
@@ -103,12 +104,13 @@ class Request:
     output: Optional[list] = None
     truncated: bool = False        # hard-stopped at max_len / page pool dry
     arrival: int = -1              # submit order (scheduler tiebreak)
-    # latency accounting (Engine.stats aggregates p50/p99): stamped from
-    # one wall-clock read per tick, so the counters cost no extra syscalls
+    # latency accounting (Engine.stats aggregates p50/p99): tokens are
+    # stamped once they reach the host — after the decode readback (one
+    # clock read per tick) or the prefill's first-token readback
     queued_ticks: int = -1         # ticks spent waiting for a slot
     ttft_ms: float = -1.0          # submit -> first token
     _t_submit: float = -1.0
-    _t_last: float = -1.0          # previous token's tick timestamp
+    _t_last: float = -1.0          # previous token's host timestamp
     _tick_submit: int = -1
 
 
@@ -154,14 +156,13 @@ class EngineConfig:
                                    # interleaved ticks' pad garbage
     scheduler: Any = "fifo"        # admission policy name or Scheduler
                                    # instance ("fifo"|"priority"|"prefix")
-    telemetry: Any = None          # observability (DESIGN.md §16): None/
-                                   # False disables every hook (zero
-                                   # overhead — no events, no timestamps,
-                                   # no allocation); True/"on" records
-                                   # the full span trace + flight ring;
-                                   # "flight" keeps only the crash ring;
-                                   # or a telemetry.TelemetryConfig /
-                                   # Tracer instance
+    telemetry: Any = None          # observability (DESIGN.md §16): None
+                                   # disables every Tracer hook (no
+                                   # events, no timestamps, no
+                                   # allocation; the tick phases' profiler
+                                   # annotations stay); True records the
+                                   # full span trace + flight ring; or a
+                                   # telemetry.TelemetryConfig
     warmup: str = "none"           # "decode": pre-trace the decode step's
                                    # proven signature ladder (and autotune
                                    # native kernels) at construction, so
@@ -312,7 +313,8 @@ class Engine:
             "generated_tokens": 0, "finished_requests": 0,
             "table_uploads": 0, "table_uploads_decode": 0,
             "table_uploads_prefill": 0, "decode_ticks": 0,
-            "prefill_chunks": 0, "paused_prefills": 0})
+            "prefill_chunks": 0, "paused_prefills": 0,
+            "admitted_requests": 0, "queue_wait_us": 0})
         self.counters: Dict[str, int] = self.metrics.counters
         self._arrival = 0
         self._tick = 0
@@ -655,8 +657,8 @@ class Engine:
             self._mark_tables_dirty()
         return True
 
-    def _exec_chunks(self, slot: int, part: _PartialPrefill, upto: int,
-                     now: float) -> Optional[Request]:
+    def _exec_chunks(self, slot: int, part: _PartialPrefill,
+                     upto: int) -> Optional[Request]:
         """Run schedule chunks ``[part.next_chunk, upto)`` through the
         jitted single-row prefill.  The caller reserved the pages
         (``_reserve_chunks``) and flushed the table mirror, so the view's
@@ -728,8 +730,9 @@ class Engine:
                       "%d/%d)", req.request_id, part.pos, L,
                       part.next_chunk, len(part.schedule))
             return None
-        part.last_tok = int(nxt)  # sync: required — prefill's first token feeds host-side finish/stream logic
-        return self._complete_admission(slot, now)
+        with phase(tr, "prefill_wait"):
+            part.last_tok = int(nxt)  # sync: required — prefill's first token feeds host-side finish/stream logic
+        return self._complete_admission(slot)
 
     # ---- public API ----
     def submit(self, req: Request):
@@ -936,20 +939,19 @@ class Engine:
     def _batch_cost(self, part: _PartialPrefill, upto: int) -> int:
         return sum(cb for _s, cb in part.schedule[part.next_chunk:upto])
 
-    def _append_token(self, req: Request, tok: int,
-                      now: Optional[float] = None):
-        """Record a generated token, stamp its latency sample, and fire
-        the streaming callback."""
+    def _append_token(self, req: Request, tok: int, now: float):
+        """Record a generated token, stamp its latency sample at ``now``
+        (the host clock once the token was read back), and fire the
+        streaming callback."""
         tok = int(tok)  # sync: host — tok is already a host-side numpy scalar here
         req.output.append(tok)
         self.counters["generated_tokens"] += 1
-        if now is not None:
-            if len(req.output) == 1:
-                req.ttft_ms = (now - req._t_submit) * 1e3
-                self._lat["ttft_ms"].record(req.ttft_ms)
-            else:
-                self._lat["itl_ms"].record((now - req._t_last) * 1e3)
-            req._t_last = now
+        if len(req.output) == 1:
+            req.ttft_ms = (now - req._t_submit) * 1e3
+            self._lat["ttft_ms"].record(req.ttft_ms)
+        else:
+            self._lat["itl_ms"].record((now - req._t_last) * 1e3)
+        req._t_last = now
         if req.on_token is not None:
             try:
                 req.on_token(req, tok)
@@ -966,12 +968,12 @@ class Engine:
         if self.paged:
             self._scrub_slot_device(slot)
 
-    def _complete_admission(self, slot: int,
-                            now: float) -> Optional[Request]:
-        """The partial finished its whole schedule: promote it to an
-        active (decoding) slot and account the admission.  Returns the
-        request when its first (prefill-produced) token was terminal —
-        EOS or max_new_tokens=1 — i.e. finish at admission."""
+    def _complete_admission(self, slot: int) -> Optional[Request]:
+        """The partial finished its whole schedule and its first token
+        was read back: promote it to an active (decoding) slot and
+        account the admission.  Returns the request when its first
+        (prefill-produced) token was terminal — EOS or max_new_tokens=1
+        — i.e. finish at admission."""
         part = self.admitting.pop(slot)
         req = part.req
         self.active[slot] = req
@@ -982,7 +984,7 @@ class Engine:
         if part.credit:
             self.counters["prefix_hit_tokens"] += part.credit
             self.counters["prefix_hit_requests"] += 1
-        self._append_token(req, part.last_tok, now)
+        self._append_token(req, part.last_tok, time.perf_counter())
         nxt = req.output[-1]
         done = (len(req.output) >= req.max_new_tokens
                 or (req.eos_id is not None and nxt == req.eos_id))
@@ -994,7 +996,6 @@ class Engine:
         return None
 
     def _advance_one(self, slot: int, quota: Optional[int], spent: int,
-                     now: float,
                      reserved_upto: Optional[int] = None
                      ) -> Tuple[int, Optional[Request]]:
         """Advance one in-progress admission by this tick's share of the
@@ -1030,7 +1031,7 @@ class Engine:
                         self._mark_tables_dirty()
                     if self.tel is not None:
                         self.tel.request_restaged(req.request_id)
-                    return self._advance_one(slot2, quota, spent, now)
+                    return self._advance_one(slot2, quota, spent)
                 self._prefill_stalled = True
                 part.paused = True
                 self.counters["paused_prefills"] += 1
@@ -1043,10 +1044,9 @@ class Engine:
         cost = self._batch_cost(part, upto)
         # the batch's table edits (growth + forks) ride ONE upload
         self._flush_tables("prefill")
-        return cost, self._exec_chunks(slot, part, upto, now)
+        return cost, self._exec_chunks(slot, part, upto)
 
-    def _run_prefills(self, quota: Optional[int],
-                      now: float) -> List[Request]:
+    def _run_prefills(self, quota: Optional[int]) -> List[Request]:
         """The tick's chunked-prefill pass: resume in-progress admissions
         first (FIFO in staging order), then admit from the scheduler
         while slots and budget allow.  Admission itself (claim + stage)
@@ -1063,79 +1063,81 @@ class Engine:
         for slot in list(self.admitting):
             if slot not in self.admitting:
                 continue        # re-staged uncached under a new slot id
-            cost, fin = self._advance_one(slot, quota, spent, now)
+            cost, fin = self._advance_one(slot, quota, spent)
             spent += cost
             if fin is not None:
                 finished.append(fin)
         tr = self.tel
-        if tr is not None:
-            tr.begin("scheduler", queued=len(self.scheduler))
-        while len(self.scheduler):
-            req = self.scheduler.next(self)
-            if req is None:
-                break
-            slot = self.alloc.claim(req.request_id)
-            if slot is None:
-                self._admission_backoff = True
-                break
-            credit, pages = self._prefix_credit(req)
-            part = _PartialPrefill(
-                req=req, schedule=self._stage_slot(slot, req, credit, pages),
-                credit=credit, pos=credit)
-            # reserve the first chunk batch BEFORE dequeuing: a pool-dry
-            # admission unwinds with the request still queued (retried
-            # uncached when a credit was mounted, backed off otherwise)
-            upto = self._plan_chunks(part, quota, spent)
-            if not self._reserve_chunks(slot, part, upto):
-                self._unwind_slot(slot)
-                if credit:
-                    # the cache must never block an admission an empty
-                    # cache would allow — retry uncached (eviction freed
-                    # what it could)
-                    slot = self.alloc.claim(req.request_id)
-                    credit, pages = 0, []
-                    part = _PartialPrefill(
-                        req=req,
-                        schedule=self._stage_slot(slot, req, 0, []))
-                    upto = self._plan_chunks(part, quota, spent)
-                    if not self._reserve_chunks(slot, part, upto):
-                        self._unwind_slot(slot)
-                        self._admission_backoff = True
-                        break
-                else:
+        with phase(tr, "scheduler", queued=len(self.scheduler)):
+            while len(self.scheduler):
+                req = self.scheduler.next(self)
+                if req is None:
+                    break
+                slot = self.alloc.claim(req.request_id)
+                if slot is None:
                     self._admission_backoff = True
                     break
-            self.scheduler.remove(req)
-            req.queued_ticks = max(0, self._tick - req._tick_submit - 1)
-            self._lat["queued_ticks"].record(req.queued_ticks)
-            self.admitting[slot] = part
-            if tr is not None:
-                tr.request_admitted(req.request_id, slot, part.credit,
-                                    len(part.schedule))
-            self._progressed = True   # claiming + staging IS progress
-            # reset this slot's cursor/recurrent state before any chunk
-            # runs (device table row = shared + fresh + forks)
-            self.states = _reset_slot(self.states, slot)
-            if self._bucketed and part.pos:
-                # pin the device cursor at the resume point right away: a
-                # credit-mounted partial that executes no chunk this tick
-                # still rides the decode step, and an unpinned (zero)
-                # cursor would scatter its garbage row into the first
-                # SHARED page instead of past the mount (page-aligned
-                # credit → the write lands on an unmapped logical page →
-                # trash page 0)
-                kv = self.states.kv
-                self.states = self.states._replace(kv=kv._replace(
-                    length=kv.length.at[:, slot].set(part.pos)))
-            if self.paged:
-                self._mark_tables_dirty()
-            cost, fin = self._advance_one(slot, quota, spent, now,
-                                          reserved_upto=upto)
-            spent += cost
-            if fin is not None:
-                finished.append(fin)
-        if tr is not None:
-            tr.end("scheduler")
+                credit, pages = self._prefix_credit(req)
+                part = _PartialPrefill(
+                    req=req,
+                    schedule=self._stage_slot(slot, req, credit, pages),
+                    credit=credit, pos=credit)
+                # reserve the first chunk batch BEFORE dequeuing: a
+                # pool-dry admission unwinds with the request still queued
+                # (retried uncached when a credit was mounted, backed off
+                # otherwise)
+                upto = self._plan_chunks(part, quota, spent)
+                if not self._reserve_chunks(slot, part, upto):
+                    self._unwind_slot(slot)
+                    if credit:
+                        # the cache must never block an admission an
+                        # empty cache would allow — retry uncached
+                        # (eviction freed what it could)
+                        slot = self.alloc.claim(req.request_id)
+                        credit, pages = 0, []
+                        part = _PartialPrefill(
+                            req=req,
+                            schedule=self._stage_slot(slot, req, 0, []))
+                        upto = self._plan_chunks(part, quota, spent)
+                        if not self._reserve_chunks(slot, part, upto):
+                            self._unwind_slot(slot)
+                            self._admission_backoff = True
+                            break
+                    else:
+                        self._admission_backoff = True
+                        break
+                self.scheduler.remove(req)
+                self.counters["admitted_requests"] += 1
+                self.counters["queue_wait_us"] += round(
+                    (time.perf_counter() - req._t_submit) * 1e6)
+                req.queued_ticks = max(0, self._tick - req._tick_submit - 1)
+                self._lat["queued_ticks"].record(req.queued_ticks)
+                self.admitting[slot] = part
+                if tr is not None:
+                    tr.request_admitted(req.request_id, slot, part.credit,
+                                        len(part.schedule))
+                self._progressed = True   # claiming + staging IS progress
+                # reset this slot's cursor/recurrent state before any
+                # chunk runs (device table row = shared + fresh + forks)
+                self.states = _reset_slot(self.states, slot)
+                if self._bucketed and part.pos:
+                    # pin the device cursor at the resume point right
+                    # away: a credit-mounted partial that executes no
+                    # chunk this tick still rides the decode step, and an
+                    # unpinned (zero) cursor would scatter its garbage row
+                    # into the first SHARED page instead of past the mount
+                    # (page-aligned credit → the write lands on an
+                    # unmapped logical page → trash page 0)
+                    kv = self.states.kv
+                    self.states = self.states._replace(kv=kv._replace(
+                        length=kv.length.at[:, slot].set(part.pos)))
+                if self.paged:
+                    self._mark_tables_dirty()
+                cost, fin = self._advance_one(slot, quota, spent,
+                                              reserved_upto=upto)
+                spent += cost
+                if fin is not None:
+                    finished.append(fin)
         return finished
 
     def cancel(self, request_id: int) -> bool:
@@ -1168,140 +1170,146 @@ class Engine:
         return False
 
     def _finish(self, slot: int):
-        req = self.active.pop(slot)
-        self.counters["finished_requests"] += 1
-        if self.tel is not None:
-            self.tel.request_finish(
-                req.request_id,
-                "truncated" if req.truncated else "finish",
-                len(req.output))
-        if self.prefix is not None:
-            # cache the finished sequence: every written KV row is valid
-            # (prompt + all-but-the-last generated token have rows), and
-            # the index takes references on the page-aligned prefix — the
-            # release below then frees only what nothing else holds
-            rows = self.alloc.slots[slot].length
-            toks = np.concatenate([
-                req.prompt,
-                np.asarray(  # sync: host — output tokens are host-side python ints
-                    req.output[:max(0, rows - len(req.prompt))], np.int32)])
-            self.prefix.insert(toks[:rows], self.alloc.held(slot))
-        self.alloc.release(slot)
-        if self.paged:
-            # the freed pages can be reacquired by other slots (or stay
-            # cached in the index) any tick — scrub the device row
-            self._scrub_slot_device(slot)
+        with phase(self.tel, "finish"):
+            req = self.active.pop(slot)
+            self.counters["finished_requests"] += 1
+            if self.tel is not None:
+                self.tel.request_finish(
+                    req.request_id,
+                    "truncated" if req.truncated else "finish",
+                    len(req.output))
+            if self.prefix is not None:
+                # cache the finished sequence: every written KV row is
+                # valid (prompt + all-but-the-last generated token have
+                # rows), and the index takes references on the
+                # page-aligned prefix — the release below then frees only
+                # what nothing else holds
+                rows = self.alloc.slots[slot].length
+                toks = np.concatenate([
+                    req.prompt,
+                    np.asarray(  # sync: host — output tokens are host-side python ints
+                        req.output[:max(0, rows - len(req.prompt))],
+                        np.int32)])
+                self.prefix.insert(toks[:rows], self.alloc.held(slot))
+            self.alloc.release(slot)
+            if self.paged:
+                # the freed pages can be reacquired by other slots (or
+                # stay cached in the index) any tick — scrub the device row
+                self._scrub_slot_device(slot)
         return req
 
     def step(self) -> List[Request]:
-        """One engine tick. Returns requests that finished this tick."""
-        # grow in-flight slots' tables for this tick's KV row BEFORE
-        # admitting — decoding requests have page priority over new
-        # admissions (an admission must never drain the free list out
-        # from under a request that only needed one more page).  Slots at
-        # max_len hard-stop: decoding past it would clamp the write
-        # offset and corrupt the newest rows.  Newly admitted slots are
-        # covered through prompt_len + 1 by the admission ensure.
+        """One engine tick. Returns requests that finished this tick.
+
+        Each phase runs inside ``telemetry.phase``: a profiler span
+        ``engine.<phase>`` always, and the Tracer's ``<phase>`` span when
+        tracing (DESIGN.md §16)."""
         self._tick += 1
         self._progressed = False
-        now = time.perf_counter()
         tr = self.tel
-        if tr is not None:
-            tr.begin("tick", n=self._tick, active=len(self.active),
-                     admitting=len(self.admitting),
-                     queued=len(self.scheduler))
-        finished: List[Request] = []
-        for slot in list(self.active):
-            req = self.active[slot]
-            if self.alloc.slots[slot].length >= self.cfg.max_len or (
-                    self.paged and not self._ensure_pages(
-                        slot, self.alloc.slots[slot].length + 1)):
-                req.truncated = True
-                finished.append(self._finish(slot))
-                log.debug("request %d hard-stopped at max_len/page cap",
-                          req.request_id)
-        if tr is not None:
-            tr.begin("prefill_pass")
-        finished.extend(self._run_prefills(self._prefill_quota(), now))
-        if tr is not None:
-            tr.end("prefill_pass")
-        if not self.active:
-            if tr is not None:
-                tr.end("tick")
+        with phase(tr, "tick", n=self._tick, active=len(self.active),
+                   admitting=len(self.admitting),
+                   queued=len(self.scheduler)):
+            finished: List[Request] = []
+            # grow in-flight slots' tables for this tick's KV row BEFORE
+            # admitting — decoding requests have page priority over new
+            # admissions (an admission must never drain the free list out
+            # from under a request that only needed one more page).
+            # Slots at max_len hard-stop: decoding past it would clamp
+            # the write offset and corrupt the newest rows.  Newly
+            # admitted slots are covered through prompt_len + 1 by the
+            # admission ensure.
+            with phase(tr, "grow"):
+                for slot in list(self.active):
+                    req = self.active[slot]
+                    if self.alloc.slots[slot].length >= self.cfg.max_len or (
+                            self.paged and not self._ensure_pages(
+                                slot, self.alloc.slots[slot].length + 1)):
+                        req.truncated = True
+                        finished.append(self._finish(slot))
+                        log.debug("request %d hard-stopped at max_len/page "
+                                  "cap", req.request_id)
+            with phase(tr, "prefill_pass"):
+                finished.extend(self._run_prefills(self._prefill_quota()))
+            if not self.active:
+                return finished
+            with phase(tr, "decode_step", batch=len(self.active)):
+                with phase(tr, "dispatch"):
+                    last = np.zeros((self.cfg.max_batch, 1), np.int32)
+                    for slot, req in self.active.items():
+                        last[slot, 0] = req.output[-1]
+                    sub = self._next_key()
+                    # the tick's ONE batched block-table upload, then clamp
+                    # the decode tick's block-table width to the bucketed
+                    # batch high-water page count: attention (gather or
+                    # paged kernel) then only walks pages some active row
+                    # can actually hold, instead of the full pool-capacity
+                    # table.  Power-of-two buckets bound the decode
+                    # retraces by log2(pages_per_slot); tables are
+                    # restored afterwards (the decode step never rewrites
+                    # them).
+                    self._flush_tables("decode")
+                    last_dev = jnp.asarray(last)  # sync: required — the tick's last-token batch upload
+                    states_in, full_tables = self.states, None
+                    if self.paged:
+                        hw = self._decode_table_width()
+                        kv = self.states.kv
+                        full_tables = kv.block_tables
+                        states_in = self.states._replace(kv=kv._replace(
+                            block_tables=full_tables[:, :, :hw]))
+                        if hw not in self._decode_table_buckets:
+                            self._decode_table_buckets.add(hw)
+                            self._tune_decode_bucket(last_dev, states_in, sub)
+                            if tr is not None:
+                                # first tick at this table width: attach
+                                # kernel/plan provenance (which launch
+                                # config won the autotune, and why) to the
+                                # timeline + trace metadata
+                                tr.instant("decode_bucket", cat="plan",
+                                           table_width=hw,
+                                           **self._kernel_provenance())
+                    nxt, new_states = self._jit_decode(self.params, last_dev,
+                                                       states_in, sub)
+                    if full_tables is not None:
+                        kv = new_states.kv
+                        new_states = new_states._replace(
+                            kv=kv._replace(block_tables=full_tables))
+                    self.states = new_states
+                    self.counters["decode_ticks"] += 1
+                    if self.admitting and self._bucketed:
+                        # mid-prefill rows rode this decode tick as
+                        # inactive batch rows: the step advanced their
+                        # device cursors past the resume point and
+                        # scattered one garbage KV row at it.  The garbage
+                        # is harmless — the next chunk's window rewrites
+                        # that position (windows always cover the resume
+                        # point) — but the cursor must be re-pinned to
+                        # ``pos`` every tick, or an admission idling
+                        # across several ticks would drift its cursor and
+                        # scatter garbage ABOVE the resume point, beyond
+                        # the next chunk's rewrite extent (device-side
+                        # edit, no transfer).
+                        kv = self.states.kv
+                        length = kv.length
+                        for slot, part in self.admitting.items():
+                            length = length.at[:, slot].set(part.pos)
+                        self.states = self.states._replace(
+                            kv=kv._replace(length=length))
+                    self._progressed = True
+                with phase(tr, "readback"):
+                    nxt = np.asarray(nxt)  # sync: required — the tick's one d2h readback (next tokens drive host finish logic)
+            now = time.perf_counter()
+            with phase(tr, "emit"):
+                for slot in list(self.active):
+                    req = self.active[slot]
+                    self._append_token(req, nxt[slot], now)
+                    self.alloc.slots[slot].length += 1
+                    done = (len(req.output) >= req.max_new_tokens
+                            or (req.eos_id is not None
+                                and req.output[-1] == req.eos_id))
+                    if done:
+                        finished.append(self._finish(slot))
             return finished
-        last = np.zeros((self.cfg.max_batch, 1), np.int32)
-        for slot, req in self.active.items():
-            last[slot, 0] = req.output[-1]
-        sub = self._next_key()
-        # the tick's ONE batched block-table upload (replaces the old
-        # per-slot jnp.asarray loop over grown slots), then clamp the
-        # decode tick's block-table width to the bucketed batch
-        # high-water page count: attention (gather or paged kernel) then
-        # only walks pages some active row can actually hold, instead of
-        # the full pool-capacity table.  Power-of-two buckets bound the
-        # decode retraces by log2(pages_per_slot); tables are restored
-        # afterwards (the decode step never rewrites them).
-        if tr is not None:
-            tr.begin("decode_step", batch=len(self.active))
-        self._flush_tables("decode")
-        last_dev = jnp.asarray(last)  # sync: required — the tick's last-token batch upload
-        states_in, full_tables = self.states, None
-        if self.paged:
-            hw = self._decode_table_width()
-            kv = self.states.kv
-            full_tables = kv.block_tables
-            states_in = self.states._replace(
-                kv=kv._replace(block_tables=full_tables[:, :, :hw]))
-            if hw not in self._decode_table_buckets:
-                self._decode_table_buckets.add(hw)
-                self._tune_decode_bucket(last_dev, states_in, sub)
-                if tr is not None:
-                    # first tick at this table width: attach kernel/plan
-                    # provenance (which launch config won the autotune,
-                    # and why) to the timeline + trace metadata
-                    tr.instant("decode_bucket", cat="plan", table_width=hw,
-                               **self._kernel_provenance())
-        nxt, new_states = self._jit_decode(self.params, last_dev,
-                                           states_in, sub)
-        if full_tables is not None:
-            kv = new_states.kv
-            new_states = new_states._replace(
-                kv=kv._replace(block_tables=full_tables))
-        self.states = new_states
-        self.counters["decode_ticks"] += 1
-        if self.admitting and self._bucketed:
-            # mid-prefill rows rode this decode tick as inactive batch
-            # rows: the step advanced their device cursors past the
-            # resume point and scattered one garbage KV row at it.  The
-            # garbage is harmless — the next chunk's window rewrites that
-            # position (windows always cover the resume point) — but the
-            # cursor must be re-pinned to ``pos`` every tick, or an
-            # admission idling across several ticks would drift its
-            # cursor and scatter garbage ABOVE the resume point, beyond
-            # the next chunk's rewrite extent (device-side edit, no
-            # transfer).
-            kv = self.states.kv
-            length = kv.length
-            for slot, part in self.admitting.items():
-                length = length.at[:, slot].set(part.pos)
-            self.states = self.states._replace(
-                kv=kv._replace(length=length))
-        self._progressed = True
-        nxt = np.asarray(nxt)  # sync: required — the tick's one d2h readback (next tokens drive host finish logic)
-        if tr is not None:
-            tr.end("decode_step")
-        for slot in list(self.active):
-            req = self.active[slot]
-            self._append_token(req, nxt[slot], now)
-            self.alloc.slots[slot].length += 1
-            done = (len(req.output) >= req.max_new_tokens
-                    or (req.eos_id is not None
-                        and req.output[-1] == req.eos_id))
-            if done:
-                finished.append(self._finish(slot))
-        if tr is not None:
-            tr.end("tick")
-        return finished
 
     def _warmup_decode(self) -> None:
         """Pre-trace the decode step's whole signature set at construction

@@ -7,12 +7,13 @@ Three cooperating pieces, all host-only and allocation-bounded:
     tick loop.  Every request gets a lifecycle span tree on its own
     Perfetto track (submit → queued → admitted[prefix credit] → prefill
     chunk batches [paused/resumed] → decode → finish/cancel/truncated);
-    every engine tick gets phase attribution on track 0 (prefill pass,
-    scheduler, decode step) plus instants for table uploads, CoW forks,
-    evictions, and first-seen decode buckets (with kernel/plan
-    provenance attached as args).  Events are 6-tuples appended to a
-    plain list — no objects, no I/O, no device interaction — and the
-    same append feeds a bounded ``deque`` ring (the flight recorder).
+    every engine tick gets phase attribution on track 0 (the
+    :data:`PHASES`, through :func:`phase`) plus instants for table
+    uploads, CoW forks, evictions, and first-seen decode buckets (with
+    kernel/plan provenance attached as args).  Events are 6-tuples
+    appended to a plain list — no objects, no I/O, no device
+    interaction — and the same append feeds a bounded ``deque`` ring
+    (the flight recorder).
   * **Flight recorder** — the last ``ring`` events, dumped to JSON by
     the engine's error paths (:func:`dump_flight`): a crash leaves the
     final K scheduling decisions on disk even when no trace was
@@ -23,14 +24,25 @@ Three cooperating pieces, all host-only and allocation-bounded:
     O(capacity log capacity) percentile cost no matter how long the
     engine runs.
 
-**Zero-overhead-off contract:** the engine holds ``self.tel = None``
-when telemetry is disabled and guards every hook with one attribute
-load + ``is not None`` — no event tuples, no ring, no timestamps.  The
-host-sync audit (``repro.analysis.serve_static.audit_telemetry_file``)
-closes the call graph over the emit-path functions below and proves
-they perform **zero** host<->device transfers, so instrumentation can
-never add h2d/d2h traffic to the tick path (the engine's own 2 h2d +
-1 d2h contract is audited separately and unchanged).
+**Tick phases on the profiler's clock:** :func:`phase` is the one span
+helper the engine wraps each phase of ``Engine.step`` in (:data:`PHASES`).
+It always enters a ``jax.profiler.TraceAnnotation`` named
+``engine.<phase>`` — a TraceMe records only while a profiler session is
+active, so the device trace gets the host's tick phases beside its
+device ops — and, when tracing, records the same phase as B/E events in
+the Tracer.
+
+**Zero-overhead-off contract:** "off" means no Tracer: the engine holds
+``self.tel = None`` and guards every hook with one attribute load +
+``is not None`` — no event tuples, no ring, no timestamps.  The profiler
+annotations of :func:`phase` are always entered (about ten per tick,
+some 2 us each, helper included, on a CPU host with no profiler
+session).  The host-sync audit
+(``repro.analysis.serve_static.audit_telemetry_file``) closes the call
+graph over the emit-path functions below and proves they perform
+**zero** host<->device transfers, so instrumentation can never add
+h2d/d2h traffic to the tick path (the engine's own 2 h2d + 1 d2h
+contract is audited separately and unchanged).
 
 Exporter writes Chrome trace-event JSON loadable in Perfetto
 (https://ui.perfetto.dev).  CLI validates + summarizes a trace::
@@ -44,6 +56,7 @@ span that never reaches a terminal event.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import random
@@ -53,6 +66,7 @@ from pathlib import Path
 from typing import Any, Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 SCHEMA = 1
 
@@ -67,10 +81,19 @@ REQ_TID_BASE = 1000
 #: terminal request states (exactly one instant per request track)
 TERMINALS = ("finish", "cancel", "truncated")
 
+#: the engine's tick phases, nested as ``tick`` > ``grow``,
+#: ``prefill_pass`` > (``scheduler``, ``prefill_wait``), ``decode_step`` >
+#: (``dispatch``, ``readback``), ``emit``, with ``finish`` wherever a
+#: request finishes and ``prefill_wait`` under ``scheduler`` too: the
+#: Tracer's span name -> the profiler annotation's name
+PHASES = {p: f"engine.{p}" for p in (
+    "tick", "grow", "prefill_pass", "scheduler", "prefill_wait",
+    "decode_step", "dispatch", "readback", "emit", "finish")}
+
 __all__ = [
-    "SCHEMA", "PID", "TID_ENGINE", "REQ_TID_BASE", "TERMINALS",
+    "SCHEMA", "PID", "TID_ENGINE", "REQ_TID_BASE", "TERMINALS", "PHASES",
     "Histogram", "MetricsRegistry", "TelemetryConfig", "Tracer",
-    "make_tracer", "to_chrome_trace", "write_trace", "dump_flight",
+    "phase", "make_tracer", "to_chrome_trace", "write_trace", "dump_flight",
     "validate_chrome_trace", "summarize_chrome_trace", "main",
 ]
 
@@ -195,7 +218,7 @@ class TelemetryConfig:
 class Tracer:
     """Append-only span/event recorder.  An event is the 6-tuple
     ``(ts_us, ph, name, cat, tid, args)`` — ``ph`` is the Chrome
-    trace-event phase (B/E/i/X/C).  Emission is two list appends at
+    trace-event phase (B/E/i/X).  Emission is two list appends at
     most; export/validation cost is paid only when a trace is written.
 
     The ``request_*`` helpers encode the lifecycle span grammar in ONE
@@ -252,16 +275,10 @@ class Tracer:
         args["_dur"] = self.now() - start
         self._emit(start, "X", name, cat, tid, args)
 
-    def counter(self, name: str, tid: int = TID_ENGINE, **series) -> None:
-        self._emit(None, "C", name, "tick", tid, series)
-
     def set_meta(self, key: str, value: Any) -> None:
         """Trace-level metadata (plan/kernel provenance, engine config);
         exported under ``otherData.meta``, JSON-serializable values."""
         self.meta[key] = value
-
-    def set_thread_name(self, tid: int, label: str) -> None:
-        self.thread_names[tid] = label
 
     # ---- request lifecycle grammar ----
     def request_submit(self, rid: int, prompt_len: int,
@@ -322,23 +339,33 @@ class Tracer:
 def make_tracer(spec) -> Optional[Tracer]:
     """``EngineConfig.telemetry`` -> Tracer or None (disabled).
 
-    ``None``/``False`` -> disabled (the zero-overhead default);
-    ``True``/``"on"`` -> full tracing; ``"flight"`` -> flight-recorder
-    ring only (no event list); a :class:`TelemetryConfig` or an existing
-    :class:`Tracer` pass through."""
-    if spec is None or spec is False:
+    ``None`` -> disabled (the zero-overhead default); ``True`` -> full
+    tracing; a :class:`TelemetryConfig` sets the event list, the
+    flight-recorder ring and its dump path."""
+    if spec is None:
         return None
-    if isinstance(spec, Tracer):
-        return spec
-    if spec is True or spec == "on":
+    if spec is True:
         return Tracer()
-    if spec == "flight":
-        return Tracer(trace=False)
     if isinstance(spec, TelemetryConfig):
         return Tracer(trace=spec.trace, ring=spec.ring,
                       flight_path=spec.flight_path)
     raise ValueError(f"unknown telemetry spec {spec!r} (expected None, "
-                     f"bool, 'on', 'flight', TelemetryConfig, or Tracer)")
+                     f"True or a TelemetryConfig)")
+
+
+@contextlib.contextmanager
+def phase(tel: Optional[Tracer], name: str, **args):
+    """One engine tick phase (a key of :data:`PHASES`) as a host span: a
+    profiler ``TraceAnnotation`` ``engine.<name>``, always, and B/E
+    events under ``name`` in ``tel`` when tracing.  An exception leaves
+    the Tracer's span open, so a flight dump shows where it was raised."""
+    with TraceAnnotation(PHASES[name]):
+        if tel is None:
+            yield
+        else:
+            tel.begin(name, **args)
+            yield
+            tel.end(name)
 
 
 # --------------------------------------------------------------------------

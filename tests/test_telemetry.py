@@ -8,13 +8,17 @@ Covers the observability contract end to end:
   (cancel-while-decoding), and the pool-dry pause/resume path;
 * flight-recorder dump on the no-progress error path (the dump exists,
   carries the reason, and the exception message points at it);
+* the tick phases as ``engine.*`` profiler spans (on the Python thread's
+  line of a CPU profiler trace, nested, one ``engine.tick`` per step)
+  and as the same Tracer spans;
 * Chrome trace-event schema validation + the module CLI as a hard gate;
 * disabled-mode zero overhead: ``telemetry=None`` constructs NO tracer
   and emits NO events (proven by making every Tracer constructor blow
   up for the duration of the run);
 * bounded reservoir histograms replacing the unbounded latency lists,
   with the engine's ``*_p50`` / ``*_p99`` / ``latency_samples`` stats
-  surface intact;
+  surface intact, latency stamped after the readback, and the
+  admission queue-wait counters;
 * the telemetry emit path itself stays transfer-free and LANE004-clean
   (``audit_telemetry_file`` + the lane lint).
 """
@@ -189,6 +193,100 @@ def test_eviction_and_cow_instants(serve_model):
 
 
 # ---------------------------------------------------------------------------
+# tick phases on the profiler's clock
+# ---------------------------------------------------------------------------
+
+#: each tick phase's allowed innermost enclosing phase (None: top level)
+_PHASE_PARENTS = {
+    "tick": {None}, "grow": {"tick"}, "prefill_pass": {"tick"},
+    "scheduler": {"prefill_pass"},
+    "prefill_wait": {"scheduler", "prefill_pass"},
+    "decode_step": {"tick"}, "dispatch": {"decode_step"},
+    "readback": {"decode_step"}, "emit": {"tick"},
+    "finish": {"grow", "scheduler", "prefill_pass", "emit"},
+}
+
+
+def test_tick_phases_on_the_profiler_clock(serve_model, tmp_path):
+    """Every ``engine.<phase>`` span sits on the Python thread's line (the
+    one holding the caller's own annotation), nests as ``PHASES`` says,
+    one ``engine.tick`` per ``step()``, and the Tracer records the same
+    phase names."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData, TraceAnnotation
+
+    from repro.serve.engine import Request
+
+    eng = _mk_engine(serve_model, tick_budget=12)
+    for i, p in enumerate(_prompts(41, (3, 17, 9))):
+        eng.submit(Request(i, p, max_new_tokens=3))
+    steps = 0
+    with jax.profiler.trace(str(tmp_path)):
+        with TraceAnnotation("test.steps"):
+            while eng.active or eng.admitting or len(eng.scheduler):
+                eng.step()
+                steps += 1
+    [path] = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                           "*.xplane.pb"))
+    lines = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            evs = [(e.name, int(e.start_ns), int(e.start_ns + e.duration_ns))
+                   for e in line.events]
+            if any(n == "test.steps" for n, _, _ in evs):
+                lines[f"{plane.name}/{line.name}"] = evs
+    assert len(lines) == 1
+    [evs] = lines.values()
+    spans = sorted(((n[len("engine."):], a, b) for n, a, b in evs
+                    if n.startswith("engine.")),
+                   key=lambda s: (s[1], -s[2]))
+    names = [n for n, _, _ in spans]
+    assert set(names) <= set(telemetry.PHASES)
+    assert {"tick", "grow", "prefill_pass", "scheduler", "prefill_wait",
+            "decode_step", "dispatch", "readback", "emit",
+            "finish"} <= set(names)
+    assert names.count("tick") == steps
+    for i, (name, a, b) in enumerate(spans):
+        holders = [s for s in spans[:i] if s[1] <= a and b <= s[2]]
+        parent = holders[-1][0] if holders else None
+        assert parent in _PHASE_PARENTS[name], (name, parent)
+    # the same phases, by the same names, in the Tracer
+    traced = [e[2] for e in eng.tel.events
+              if e[1] == "B" and e[4] == telemetry.TID_ENGINE]
+    assert sorted(traced) == sorted(names)
+    _validated(eng)
+
+
+def test_phase_spans_are_entered_with_telemetry_off(monkeypatch):
+    """Off means no Tracer, not no spans: the profiler annotation is
+    entered either way, the Tracer's events only when tracing."""
+    entered = []
+
+    class Ann:
+        def __init__(self, name):
+            entered.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(telemetry, "TraceAnnotation", Ann)
+    with telemetry.phase(None, "tick", n=1):
+        with telemetry.phase(None, "readback"):
+            pass
+    tr = Tracer()
+    with telemetry.phase(tr, "emit"):
+        pass
+    assert entered == ["engine.tick", "engine.readback", "engine.emit"]
+    assert [(e[1], e[2]) for e in tr.events] == [("B", "emit"),
+                                                ("E", "emit")]
+
+
+# ---------------------------------------------------------------------------
 # flight recorder
 # ---------------------------------------------------------------------------
 
@@ -335,17 +433,12 @@ def test_disabled_mode_constructs_no_tracer(serve_model, monkeypatch,
 
 def test_make_tracer_specs():
     assert make_tracer(None) is None
-    assert make_tracer(False) is None
     assert make_tracer(True).events == []
-    assert make_tracer("on").events == []
-    fl = make_tracer("flight")
-    assert fl.events is None and fl.ring is not None
-    t = Tracer()
-    assert make_tracer(t) is t
     c = make_tracer(TelemetryConfig(trace=False, ring=7, flight_path="x"))
     assert c.events is None and c.ring.maxlen == 7 and c.flight_path == "x"
-    with pytest.raises(ValueError):
-        make_tracer("bogus")
+    for bad in ("bogus", False, "on", "flight", Tracer()):
+        with pytest.raises(ValueError):
+            make_tracer(bad)
 
 
 # ---------------------------------------------------------------------------
@@ -404,6 +497,68 @@ def test_engine_latency_stats_surface_bounded(serve_model):
         assert eng._lat[k].capacity == 512    # bounded, not a list
     assert s["latency_samples"]["ttft_ms"] == 3
     assert s["latency_samples"]["itl_ms"] == 3 * 4   # n_new - 1 per req
+
+
+def test_engine_latency_stamped_after_readback(serve_model):
+    """TTFT and ITL samples are taken once the token is on the host: with
+    every prefill and decode dispatch taking ``delay`` before its
+    readback, no sample is shorter than that.  Stamped at the tick's
+    start, a first token read back in its admission tick would show a
+    TTFT of about 0 and the next token, decoded in that same tick, an
+    ITL of 0."""
+    import time
+
+    from repro.serve.engine import Request
+
+    delay = 0.02
+
+    def slow(fn):
+        def run(*args):
+            out = fn(*args)
+            time.sleep(delay)
+            return out
+        return run
+
+    eng = _mk_engine(serve_model, telemetry=None)
+    eng._jit_prefill_chunk = slow(eng._jit_prefill_chunk)
+    eng._jit_decode = slow(eng._jit_decode)
+    for i, p in enumerate(_prompts(42, (5, 7))):
+        eng.submit(Request(i, p, max_new_tokens=4))
+    eng.run_to_completion()
+    lat = eng._lat
+    assert lat["ttft_ms"].count == 2 and lat["itl_ms"].count == 2 * 3
+    assert lat["ttft_ms"].min >= delay * 1e3
+    assert lat["itl_ms"].min >= delay * 1e3
+
+
+def test_queue_wait_counters_advance_on_admission(serve_model):
+    """``admitted_requests`` counts requests as they leave the scheduler
+    for a slot, and ``queue_wait_us`` adds each one's wait since
+    ``submit``: a request held back behind a full slot pool waits at
+    least the ticks it stayed queued."""
+    import time
+
+    from repro.serve.engine import Request
+
+    eng = _mk_engine(serve_model, max_batch=1, telemetry=None)
+    first, second = _prompts(43, (4, 6))
+    s = eng.stats()
+    assert (s["admitted_requests"], s["queue_wait_us"]) == (0, 0)
+    t_submit = time.perf_counter()
+    eng.submit(Request(0, first, max_new_tokens=3))
+    eng.submit(Request(1, second, max_new_tokens=3))
+    time.sleep(0.01)
+    eng.step()
+    t_step = (time.perf_counter() - t_submit) * 1e6
+    s = eng.stats()
+    assert s["admitted_requests"] == 1            # one slot: one admitted
+    assert 10_000 <= s["queue_wait_us"] <= t_step
+    eng.run_to_completion()
+    t_end = (time.perf_counter() - t_submit) * 1e6
+    s2 = eng.stats()
+    assert s2["admitted_requests"] == 2
+    # the second request stayed queued through the first tick
+    assert t_step <= s2["queue_wait_us"] - s["queue_wait_us"] <= t_end
 
 
 # ---------------------------------------------------------------------------

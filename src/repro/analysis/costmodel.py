@@ -274,6 +274,7 @@ def roofline(costs: Costs, platform: Platform = DEFAULT_PLATFORM, *,
 # --------------------------------------------------------------------------
 
 _ITEMSIZE = 4          # kernels stage fp32 tiles in VMEM
+_LANES = 128           # keys per paged compute block by default
 _VMEM_BUDGET_FRAC = 4  # stage at most 1/4 of VMEM (double-buffering etc.)
 
 
@@ -289,14 +290,22 @@ def kernel_prior(family: str, shape_key: Sequence, choice,
     """
     vmem_cap = platform.vmem_bytes // _VMEM_BUDGET_FRAC
     if family == "paged":
+        # one grid step per block of ``pages_per_step`` live pages of a
+        # row (kernels/paged.py; unset: enough pages to fill 128 lanes);
+        # priced for one row that fills the table
         _fam, pages, page_size, h, h_kv, d = shape_key
-        pps = getattr(choice, "pages_per_step", None) or 1
-        steps = math.ceil(pages / pps)
-        staged = 2 * pps * page_size * h_kv * d * _ITEMSIZE
+        bp = (getattr(choice, "pages_per_step", None)
+              or -(-_LANES // page_size))
+        steps = math.ceil(pages / bp)
+        keys = bp * page_size
+        # K and V pages, double-buffered, plus the inhibitor's
+        # (d, keys) accumulators per query head
+        staged = (2 * 2 * keys * h_kv * d + (h + h_kv) * d * keys) \
+            * _ITEMSIZE
         if staged > vmem_cap:
             return float("inf")
-        kv_bytes = 2 * pages * page_size * h_kv * d * _ITEMSIZE
-        flops = 4.0 * pages * page_size * h * d
+        kv_bytes = 2 * steps * keys * h_kv * d * _ITEMSIZE
+        flops = 4.0 * steps * keys * h * d
         return (steps * platform.dispatch_s
                 + kv_bytes / platform.hbm_bw
                 + flops / platform.peak_flops)

@@ -127,10 +127,10 @@ CANDIDATES: Dict[str, Tuple[KernelChoice, ...]] = {
         KernelChoice(64, 128), KernelChoice(32, 128),
         KernelChoice(128, 128), KernelChoice(64, 256),
     ),
-    "paged": (
-        KernelChoice(pages_per_step=4), KernelChoice(pages_per_step=1),
-        KernelChoice(pages_per_step=2), KernelChoice(pages_per_step=8),
-    ),
+    # pages per compute block: one candidate, the block the shapes
+    # derive (kernels.paged.block_pages).  The warm-up times candidates
+    # on its own short rows, where the smallest block always wins.
+    "paged": (KernelChoice(),),
 }
 
 

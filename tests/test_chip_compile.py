@@ -9,7 +9,8 @@ stores to VMEM, and fast-memory overflow.
 
 Widths are ``smollm-135m``'s serving shapes: 9 query heads over 3 KV
 heads, head_dim 64, bf16; decode at batch 8 over 16-token pages with
-64-page block tables; prefill at 2,048 tokens.
+64-page block tables, and at the benchmark cells' batch of 48 over a
+6,145-page pool with 64- and 128-page tables; prefill at 2,048 tokens.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU
@@ -65,17 +66,23 @@ def _compile(fn, one_chip, *shapes):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("pages_per_step", [1, 4])
+@pytest.mark.parametrize(
+    "batch,num_pages,table,pages_per_step",
+    [pytest.param(BATCH, BATCH * TABLE + 1, TABLE, 1, id="1"),
+     pytest.param(BATCH, BATCH * TABLE + 1, TABLE, 4, id="4"),
+     pytest.param(48, 6145, 64, None, id="cell-table64"),
+     pytest.param(48, 6145, 128, None, id="cell-table128")])
 @pytest.mark.parametrize("kernel", [paged_flash_inhibitor_fwd,
                                     paged_flash_attention_fwd],
                          ids=["inhibitor", "dotprod"])
-def test_paged_decode_kernel_compiles_for_v5e(one_chip, kernel,
+def test_paged_decode_kernel_compiles_for_v5e(one_chip, kernel, batch,
+                                              num_pages, table,
                                               pages_per_step):
-    pool = ((BATCH * TABLE + 1, KV_HEADS, PAGE, D), DT)
+    pool = ((num_pages, KV_HEADS, PAGE, D), DT)
     _compile(lambda q, k, v, t, n: kernel(q, k, v, t, n,
                                           pages_per_step=pages_per_step),
-             one_chip, ((BATCH, 1, HEADS, D), DT), pool, pool,
-             ((BATCH, TABLE), jnp.int32), ((BATCH,), jnp.int32))
+             one_chip, ((batch, 1, HEADS, D), DT), pool, pool,
+             ((batch, table), jnp.int32), ((batch,), jnp.int32))
 
 
 @pytest.mark.parametrize("kernel", [flash_inhibitor_fwd,

@@ -6,10 +6,15 @@ registry's choice/override plumbing (DESIGN.md §10).
 The sweep covers the decode shapes the serve engine actually produces:
 GQA groups, sliding windows, ragged per-row cursors, cursors that
 straddle a page boundary / land exactly on one / sit at a single token,
-and the ``normalize`` flag.
+and the ``normalize`` flag — plus the cases the page walk creates:
+blocks that the live pages do not fill, a row filling the whole table,
+a one-column table, rows of one token, GQA groups of 1 and 7, head_dim
+128, bf16 pools, and a window whose oldest position is mid-page.
 """
 
 import dataclasses
+import math
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -24,10 +29,11 @@ from repro.kernels.paged import (paged_flash_attention_fwd,
                                  paged_flash_inhibitor_fwd)
 
 TOL = dict(rtol=1e-4, atol=1e-5)
+EPS32 = float(np.finfo(np.float32).eps)
 
 
 def _pool(rng, *, batch, pages_per_slot, page_size, kv_heads, d,
-          lengths):
+          lengths, dtype=np.float32, v_scale=1.0):
     """A ragged head-major paged pool (num_pages, kv_heads, page_size, d):
     per-row non-contiguous physical pages, trash page 0 for every
     unmapped table entry (the engine's layout)."""
@@ -35,7 +41,7 @@ def _pool(rng, *, batch, pages_per_slot, page_size, kv_heads, d,
     # drawn token-major, stored head-major: the same KV content per
     # (page, offset, head) as a token-major pool drawn from this rng
     kp = rng.normal(size=(num_pages, page_size, kv_heads, d))
-    vp = rng.normal(size=(num_pages, page_size, kv_heads, d))
+    vp = rng.normal(size=(num_pages, page_size, kv_heads, d)) * v_scale
     kp, vp = kp.transpose(0, 2, 1, 3), vp.transpose(0, 2, 1, 3)
     perm = rng.permutation(np.arange(1, num_pages))
     tables = np.zeros((batch, pages_per_slot), np.int32)
@@ -44,8 +50,8 @@ def _pool(rng, *, batch, pages_per_slot, page_size, kv_heads, d,
         used = -(-int(ln) // page_size)
         tables[b, :used] = perm[nxt:nxt + used]
         nxt += used
-    return (jnp.asarray(kp.astype(np.float32)),
-            jnp.asarray(vp.astype(np.float32)), jnp.asarray(tables),
+    return (jnp.asarray(kp.astype(np.float32), dtype),
+            jnp.asarray(vp.astype(np.float32), dtype), jnp.asarray(tables),
             jnp.asarray(np.asarray(lengths, np.int32)))
 
 
@@ -54,46 +60,163 @@ def _pool(rng, *, batch, pages_per_slot, page_size, kv_heads, d,
 RAGGED_LENGTHS = [13, 8, 1, 24]
 
 
-@pytest.mark.parametrize("signed", [True, False])
-@pytest.mark.parametrize("window", [None, 5])
-@pytest.mark.parametrize("normalize", [True, False])
-def test_paged_inhibitor_parity_sweep(rng, signed, window, normalize):
-    heads, kv_heads, d, ps = 4, 2, 16, 8       # GQA group of 2
+@dataclasses.dataclass(frozen=True)
+class Case:
+    """One decode shape: a pool of ``len(lengths)`` rows."""
+    lengths: Tuple[int, ...] = tuple(RAGGED_LENGTHS)
+    pages_per_slot: int = 4
+    page_size: int = 8
+    heads: int = 4
+    kv_heads: int = 2                  # GQA group of 2
+    d: int = 16
+    dtype: type = np.float32           # the pools'
+    q_dtype: type = np.float32
+    window: Optional[int] = None
+    pages_per_step: Optional[int] = None
+
+
+BASE = Case()
+#: shapes the page walk creates, beside the base sweep
+WALK_CASES = {
+    # 8, 5, 3 and 2 live pages in blocks of 3: every block count and
+    # remainder; the 61-token row fills its table
+    "block-remainder": Case(lengths=(61, 40, 17, 9), pages_per_slot=8,
+                            pages_per_step=3),
+    "full-table": Case(lengths=(32, 5, 32)),
+    "table-width-1": Case(lengths=(5, 8, 1), pages_per_slot=1),
+    "length-1": Case(lengths=(1, 1, 1)),
+    "group-1": Case(heads=2, kv_heads=2),
+    "group-7": Case(heads=14, kv_heads=2),
+    "d-128": Case(d=128),
+    "bf16-pools": Case(dtype=jnp.bfloat16),
+    # the serving dtypes: bf16 queries against bf16 pages
+    "bf16-queries": Case(dtype=jnp.bfloat16, q_dtype=jnp.bfloat16),
+    # oldest attended position 29 - 11 = 18: page 2, offset 2; the walk
+    # starts at page 2
+    "window-mid-page": Case(lengths=(29, 24, 13, 3), window=11),
+}
+
+
+def _case_inputs(rng, case, *, v_scale=1.0):
     kp, vp, tables, lengths = _pool(
-        rng, batch=4, pages_per_slot=4, page_size=ps, kv_heads=kv_heads,
-        d=d, lengths=RAGGED_LENGTHS)
-    q = jnp.asarray(rng.normal(size=(4, 1, heads, d)).astype(np.float32))
+        rng, batch=len(case.lengths), pages_per_slot=case.pages_per_slot,
+        page_size=case.page_size, kv_heads=case.kv_heads, d=case.d,
+        lengths=case.lengths, dtype=case.dtype, v_scale=v_scale)
+    q = jnp.asarray(rng.normal(size=(len(case.lengths), 1, case.heads,
+                                     case.d)).astype(np.float32),
+                    case.q_dtype)
+    return q, kp, vp, tables, lengths
+
+
+def _inhibitor_f64(q, kp, vp, tables, lengths, *, signed, normalize, window,
+                   score_shift=0.5):
+    """Float64 numpy oracle of the paged inhibitor (eq. 5, 7, 9), one row
+    and one head at a time from the pages themselves.  Returns the
+    output and, per row, the keys it summed and the largest |term|."""
+    q, kp, vp = (np.asarray(jnp.asarray(a, jnp.float32), np.float64)
+                 for a in (q, kp, vp))
+    tables, lengths = np.asarray(tables), np.asarray(lengths)
+    batch, _, heads, d = q.shape
+    kv_heads, ps = kp.shape[1], kp.shape[2]
+    group = heads // kv_heads
+    out = np.zeros(q.shape)
+    n_keys = np.zeros(batch)
+    scale_of_terms = np.zeros(batch)
+    for r in range(batch):
+        pos = np.arange(min(lengths[r], tables.shape[1] * ps))
+        if window is not None:
+            pos = pos[pos > lengths[r] - 1 - window]
+        pages, offs = tables[r, pos // ps], pos % ps
+        n_keys[r] = len(pos)
+        for h in range(heads):
+            k = kp[pages, h // group, offs]                    # (n, d)
+            v = vp[pages, h // group, offs]
+            z = np.abs(q[r, 0, h] - k).sum(-1) / math.sqrt(d)
+            z = np.maximum(z - score_shift, 0.0)[:, None]
+            if signed:
+                terms = (np.maximum(np.maximum(v, 0) - z, 0)
+                         - np.maximum(np.maximum(-v, 0) - z, 0))
+            else:
+                terms = np.maximum(v - z, 0)
+            out[r, 0, h] = terms.sum(0) / (max(len(pos), 1)
+                                           if normalize else 1)
+            scale_of_terms[r] = max(scale_of_terms[r],
+                                    np.abs(v).max(initial=0) + z.max(
+                                        initial=0))
+    return out, n_keys, scale_of_terms
+
+
+def _f32_sum_tolerance(n_keys, scale_of_terms, d, normalize):
+    """Per-row bound on a float32 evaluation: the score sums d terms and
+    the output n_keys, each rounding by at most eps · |term|."""
+    tol = EPS32 * (n_keys + d) * scale_of_terms
+    if normalize:
+        tol = tol / np.maximum(n_keys, 1)
+    return tol[:, None, None, None]
+
+
+def _inhibitor_parity(rng, case, *, signed, normalize, window):
+    # |v| of the order of the scores z ≈ 1.13·sqrt(d) − 0.5, so that
+    # eq. 9/10 do not cancel to zero
+    q, kp, vp, tables, lengths = _case_inputs(
+        rng, case, v_scale=math.sqrt(case.d))
     out = paged_flash_inhibitor_fwd(q, kp, vp, tables, lengths,
                                     signed=signed, normalize=normalize,
-                                    window=window, interpret=True)
-    ref = kref.paged_flash_inhibitor_ref(q, kp, vp, tables, lengths,
-                                         signed=signed, normalize=normalize,
-                                         window=window)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **TOL)
+                                    window=window,
+                                    pages_per_step=case.pages_per_step,
+                                    interpret=True)
+    ref, n_keys, scale = _inhibitor_f64(q, kp, vp, tables, lengths,
+                                        signed=signed, normalize=normalize,
+                                        window=window)
+    # the reference is not near zero: a wrong sum would show
+    assert np.abs(ref).max() > 0.05 * math.sqrt(case.d)
+    err = np.abs(np.asarray(out, np.float64) - ref)
+    # float32 sums, then rounded once to the output's dtype
+    tol = (_f32_sum_tolerance(n_keys, scale, case.d, normalize)
+           + np.abs(ref) * float(jnp.finfo(out.dtype).eps) / 2)
+    assert np.all(err <= tol), float((err / tol).max())
 
 
-@pytest.mark.parametrize("window", [None, 5])
-def test_paged_attention_parity_sweep(rng, window):
-    heads, kv_heads, d, ps = 4, 2, 16, 8
-    kp, vp, tables, lengths = _pool(
-        rng, batch=4, pages_per_slot=4, page_size=ps, kv_heads=kv_heads,
-        d=d, lengths=RAGGED_LENGTHS)
-    q = jnp.asarray(rng.normal(size=(4, 1, heads, d)).astype(np.float32))
+@pytest.mark.parametrize(
+    "signed,window,normalize,case",
+    [pytest.param(s, w, n, BASE, id=f"{s}-{w}-{n}")
+     for s in (True, False) for w in (None, 5) for n in (True, False)]
+    + [pytest.param(True, c.window, True, c, id=name)
+       for name, c in WALK_CASES.items()])
+def test_paged_inhibitor_parity_sweep(rng, signed, window, normalize, case):
+    _inhibitor_parity(rng, case, signed=signed, normalize=normalize,
+                      window=window)
+
+
+@pytest.mark.parametrize(
+    "window,case",
+    [pytest.param(w, BASE, id=str(w)) for w in (None, 5)]
+    + [pytest.param(c.window, c, id=name)
+       for name, c in WALK_CASES.items()])
+def test_paged_attention_parity_sweep(rng, window, case):
+    q, kp, vp, tables, lengths = _case_inputs(rng, case)
     out = paged_flash_attention_fwd(q, kp, vp, tables, lengths,
-                                    window=window, interpret=True)
-    ref = kref.paged_flash_attention_ref(q, kp, vp, tables, lengths,
-                                         window=window)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **TOL)
+                                    window=window,
+                                    pages_per_step=case.pages_per_step,
+                                    interpret=True)
+    ref = kref.paged_flash_attention_ref(q.astype(jnp.float32), kp, vp,
+                                         tables, lengths, window=window)
+    # a bf16 output is the float32 result rounded to 8 bits
+    tol = TOL if out.dtype == jnp.float32 else dict(rtol=2 ** -8, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               **tol)
 
 
-@pytest.mark.parametrize("pps", [1, 2, 3, 4])
+@pytest.mark.parametrize("pps", [1, 2, 3, 4, 5, 8, 16, None])
 def test_pages_per_step_is_semantics_free(rng, pps):
-    """Every pages_per_step staging produces the same result — it is a
-    launch-configuration knob, not a semantic one."""
+    """Every compute-block size produces the same result — it is a
+    launch-configuration knob, not a semantic one.  The 64-token row
+    fills its eight-column table; ``None`` is the block the shapes
+    derive."""
     kp, vp, tables, lengths = _pool(
-        rng, batch=3, pages_per_slot=4, page_size=8, kv_heads=2, d=16,
-        lengths=[13, 8, 32])
-    q = jnp.asarray(rng.normal(size=(3, 1, 4, 16)).astype(np.float32))
+        rng, batch=4, pages_per_slot=8, page_size=8, kv_heads=2, d=16,
+        lengths=[13, 8, 64, 37])
+    q = jnp.asarray(rng.normal(size=(4, 1, 4, 16)).astype(np.float32))
     base = paged_flash_inhibitor_fwd(q, kp, vp, tables, lengths,
                                      pages_per_step=1, interpret=True)
     out = paged_flash_inhibitor_fwd(q, kp, vp, tables, lengths,
@@ -384,10 +507,15 @@ def test_choose_records_decision_provenance():
     assert ("paged",) + key in rt.priors
 
 
-def test_autotune_logs_dropped_candidates_and_raises_when_all_fail(caplog):
+def test_autotune_logs_dropped_candidates_and_raises_when_all_fail(
+        caplog, monkeypatch):
     """A candidate that raises during timing drops out with its error on
     record; when every candidate fails, the first error propagates
-    instead of silently pinning a default that was never shown to run."""
+    instead of silently pinning a default that was never shown to run.
+    (The paged family ships one candidate, the block its shapes derive;
+    the race is run over four.)"""
+    monkeypatch.setitem(kops.CANDIDATES, "paged", tuple(
+        kops.KernelChoice(pages_per_step=n) for n in (4, 1, 2, 8)))
     r = kops.KernelRegistry()
     r._platform = "tpu"
     key = ("probe", 4, 16, 9, 3, 64)

@@ -12,10 +12,11 @@ The program is reached only through ``repro.launch.serve.build_engine``,
 ``stats`` and ``params``.
 
 One run: build the engine (set-up), put the benchmark's seeded weights
-into it, ramp the traffic up, measure for ``seconds``, free the engine,
-then compare a sample of the served requests with the plain reference.
-With ``trace`` a profiler trace covers a few seconds in the middle of the
-window and the per-layer metrics are read from it.
+into it in place of the program's own (``weights.place``), ramp the
+traffic up, measure for ``seconds``, free the engine, then compare a
+sample of the served requests with the plain reference.  With ``trace``
+a profiler trace covers a few seconds in the middle of the window and
+the per-layer metrics are read from it.
 """
 
 from __future__ import annotations
@@ -154,7 +155,7 @@ def build_engine(c: dict, seed: int):
                      prefix_cache=e["prefix_cache"], greedy=True,
                      warmup=e["warmup"]),
         reduced=bool(c.get("program_reduced")), seed=small_seed)
-    eng.params = weights.to_program(c, seed, eng.params)
+    weights.place(c, seed, eng)
     return eng
 
 
@@ -224,11 +225,12 @@ class Window:
 
 def drive(eng, gen: traffic.Traffic, seconds: float, *,
           trace_dir: Optional[str] = None, compiles: Optional[_Compiles] = None,
-          log=print) -> Window:
+          min_finished: int = 0, log=print) -> Window:
     """Ramp up, then measure ``seconds``.  Closed loop: the first wave
     fills every slot, and the window opens once each of its requests has
     its first token.  Open loop: the schedule runs ``ramp_s`` seconds
-    before the window opens."""
+    before the window opens.  With ``min_finished`` the window also stays
+    open until that many requests have finished, the ramp's included."""
     import jax
     from repro.serve.engine import Request
 
@@ -321,7 +323,7 @@ def drive(eng, gen: traffic.Traffic, seconds: float, *,
     profiler_s = 0.0
     while True:
         now = clock()
-        if now >= t1:
+        if now >= t1 and len(finished) >= min_finished:
             break
         if trace_state == "pending" and now >= t_trace:
             tracemod.start(trace_dir)

@@ -6,7 +6,8 @@ call them.  Each runs in one process, which holds the chip.
     python3 bench/tools.py trace --workload W --seed 5 --seconds 2 --out DIR
 
 ``calibrate`` serves the cell once per seed with one engine (the weights
-swapped per seed, leftover requests cancelled between seeds) and prints,
+swapped per seed, leftover requests cancelled between seeds; the window
+stays open until a full sample has finished) and prints,
 per seed, the compared number and, with ``--control``, the control's
 number at the same positions: the readings the limits are set from.
 ``sweep`` offers a chat mix at each session rate and prints whether the
@@ -96,11 +97,12 @@ def calibrate(args) -> None:
         if eng is None:
             eng = harness.build_engine(c, seed)
         else:
-            eng.params = weights.to_program(c, seed, eng.params)
+            weights.place(c, seed, eng)
         setup = time.perf_counter() - t0
         gen = traffic.Traffic(cell.mix, vocab=c["vocab_size"],
                               max_len=c["engine"]["max_len"], seed=seed)
-        win = harness.drive(eng, gen, args.seconds, compiles=compiles)
+        win = harness.drive(eng, gen, args.seconds, compiles=compiles,
+                            min_finished=check.SAMPLE)
         _drain(eng, win)
         seqs = check.sample(win.finished, seed)
         t_ref = time.perf_counter()

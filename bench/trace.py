@@ -9,8 +9,13 @@ holds one event per execution of a compiled program (named
 per operation, named by its HLO text (``%copy.88 = bf16[...] copy(...)``).
 A Pallas kernel is an op whose text holds
 ``custom_call_target="tpu_custom_call"``; its kernel's name is not in the
-trace.  Op events carry no module of their own: each is given the program
-whose execution on the same device holds its start.  The host plane's
+trace.  Each op's metadata on the device plane carries its name path
+(the ``tf_op`` stat, e.g.
+``jit(_decode_step)/while/body/closed_call/checkpoint/pallas_call``, with
+any ``jax.named_scope`` around the call in it), which ``ProfileData`` does
+not expose: :func:`op_paths` reads it from the serialized trace.  Op
+events carry no module of their own: each is given the program whose
+execution on the same device holds its start.  The host plane's
 Python thread carries the benchmark's ``TraceAnnotation`` spans
 (``bench.traced``, ``bench.step``, ``bench.submit``, ``bench.wait``)
 beside JAX's own.
@@ -37,6 +42,7 @@ class Event:
     start: int       # ns
     end: int         # ns
     module: str = ""
+    path: str = ""   # an op's name path, where the trace gives one
 
 
 @dataclasses.dataclass
@@ -47,14 +53,16 @@ class Trace:
 
     # ---- construction ----
     @classmethod
-    def from_profile(cls, pd) -> "Trace":
+    def from_profile(cls, pd, paths=None) -> "Trace":
+        """``paths``: plane -> op name -> name path (:func:`op_paths`)."""
         ops, modules, host = {}, {}, []
         for plane in pd.planes:
             if _DEVICE.match(plane.name):
                 raw = []
                 for line in plane.lines:
                     if line.name == "XLA Ops":
-                        raw = [_event(e) for e in line.events]
+                        named = (paths or {}).get(plane.name, {})
+                        raw = [_event(e, named) for e in line.events]
                     elif line.name == "XLA Modules":
                         modules[plane.name] = [_event(e) for e in line.events]
                 ops[plane.name] = _in_modules(raw, modules.get(plane.name, []))
@@ -69,7 +77,8 @@ class Trace:
     def from_bytes(cls, data: bytes) -> "Trace":
         from jax.profiler import ProfileData
 
-        return cls.from_profile(ProfileData.from_serialized_xspace(data))
+        return cls.from_profile(ProfileData.from_serialized_xspace(data),
+                                op_paths(data))
 
     # ---- the traced window ----
     def span(self) -> Tuple[int, int]:
@@ -97,15 +106,21 @@ class Trace:
                if e.name.startswith(prefix) and lo <= e.start < hi]
         return sum(e.end - e.start for e in evs) / 1e9, len(evs)
 
+    def op_events(self, names: Sequence[str],
+                  program: str = "") -> Dict[str, List[Event]]:
+        """Per device, the operations whose name contains any of ``names``
+        and whose program's name starts with ``program``, inside the
+        traced window."""
+        lo, hi = self.span()
+        return {dev: [e for e in m if lo <= e.start < hi
+                      and e.module.startswith(program)
+                      and any(n in e.name for n in names)]
+                for dev, m in self.ops.items()}
+
     def op_time(self, names: Sequence[str],
                 program: str = "") -> Tuple[float, int]:
-        """(seconds, events) of device operations whose name contains any
-        of ``names`` and whose program's name starts with ``program``,
-        inside the traced window, summed over devices."""
-        lo, hi = self.span()
-        evs = [e for m in self.ops.values() for e in m
-               if lo <= e.start < hi and e.module.startswith(program)
-               and any(n in e.name for n in names)]
+        """(seconds, events) of :meth:`op_events`, summed over devices."""
+        evs = [e for m in self.op_events(names, program).values() for e in m]
         return sum(e.end - e.start for e in evs) / 1e9, len(evs)
 
     # ---- breakdown ----
@@ -151,12 +166,93 @@ class Trace:
         return out
 
 
-def _event(e) -> Event:
+def _event(e, paths=None) -> Event:
     stats = {k: v for k, v in e.stats} if e.stats is not None else {}
     module = stats.get("hlo_module", "")
     start = int(e.start_ns)
     return Event(e.name, start, start + int(e.duration_ns),
-                 module if isinstance(module, str) else "")
+                 module if isinstance(module, str) else "",
+                 (paths or {}).get(e.name, ""))
+
+
+# ---- the serialized trace (tsl/profiler/protobuf/xplane.proto) ----
+
+def _varint(buf, i: int) -> Tuple[int, int]:
+    out = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        out |= (b & 0x7F) << shift
+        shift += 7
+        if b < 0x80:
+            return out, i
+
+
+def _fields(buf):
+    """(field number, value) of one protobuf message; a length-delimited
+    value is a memoryview into ``buf``, so a skipped field costs nothing."""
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _varint(buf, i)
+        wire = key & 7
+        if wire == 0:
+            v, i = _varint(buf, i)
+        elif wire == 2:
+            size, i = _varint(buf, i)
+            v, i = buf[i:i + size], i + size
+        elif wire in (1, 5):
+            size = 8 if wire == 1 else 4
+            v, i = buf[i:i + size], i + size
+        else:
+            raise ValueError(f"unknown protobuf wire type {wire}")
+        yield key >> 3, v
+
+
+def _map_values(entries) -> Dict[int, memoryview]:
+    """A protobuf map<int64, message>: key (field 1) -> value (field 2)."""
+    out = {}
+    for entry in entries:
+        kv = dict(_fields(entry))
+        out[kv.get(1, 0)] = kv.get(2, memoryview(b""))
+    return out
+
+
+def op_paths(data: bytes) -> Dict[str, Dict[str, str]]:
+    """Device plane -> op name -> the op's name path (the ``tf_op`` stat
+    of its event metadata, without the trailing ``:<type>``).  XSpace
+    field 1 is its planes; XPlane 2 its name, 4 its event metadata and 5
+    its stat metadata; XEventMetadata 2 its name and 5 its stats; an
+    XStat holds its metadata id in 1 and a string in 5, or in 7 as the id
+    of a stat metadata whose name is the string."""
+    out = {}
+    for num, plane in _fields(memoryview(data)):
+        if num != 1:
+            continue
+        name, events, stats = "", [], []
+        for f, v in _fields(plane):
+            if f == 2:
+                name = bytes(v).decode()
+            elif f == 4:
+                events.append(v)
+            elif f == 5:
+                stats.append(v)
+        if not _DEVICE.match(name):
+            continue
+        stat_names = {k: bytes(dict(_fields(v)).get(2, b"")).decode()
+                      for k, v in _map_values(stats).items()}
+        tf_op = [k for k, n in stat_names.items() if n == "tf_op"]
+        named = {}
+        for meta in _map_values(events).values():
+            fields = list(_fields(meta))
+            op = next((bytes(v).decode() for f, v in fields if f == 2), "")
+            for f, v in fields:
+                stat = dict(_fields(v)) if f == 5 else {}
+                if tf_op and stat.get(1) == tf_op[0]:
+                    path = (bytes(stat[5]).decode() if 5 in stat
+                            else stat_names.get(stat.get(7), ""))
+                    named[op] = path.rpartition(":")[0] or path
+        out[name] = named
+    return out
 
 
 def _in_modules(ops: List[Event], modules: List[Event]) -> List[Event]:

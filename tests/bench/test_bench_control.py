@@ -21,6 +21,12 @@ def test_sound_run_is_correct(mechanism, mix):
     assert list(res)[-1] == "checks"
 
 
+#: requests the control's runs drive to their end: more than a sample, so
+#: the sample is drawn, and a fixed count, so a loaded CPU serves the same
+#: requests as an idle one
+CONTROL_FINISHED = 2 * check.SAMPLE
+
+
 @pytest.mark.parametrize("mechanism", ["inhibitor", "dotprod"])
 def test_float8_control_fails_the_limit(mechanism):
     cell = tiny_cell(mechanism, "offline")
@@ -29,7 +35,8 @@ def test_float8_control_fails_the_limit(mechanism):
         eng = harness.build_engine(c, seed)
         gen = traffic.Traffic(cell.mix, vocab=c["vocab_size"],
                               max_len=c["engine"]["max_len"], seed=seed)
-        win = harness.drive(eng, gen, 1.0)
+        win = harness.drive(eng, gen, 0.0, min_finished=CONTROL_FINISHED)
+        assert len(win.finished) >= CONTROL_FINISHED
         seqs = check.sample(win.finished, seed)
         gap, ctrl, n = check.compare(c, weights.make(c, seed), seqs,
                                      control=True)

@@ -107,6 +107,68 @@ def test_traced_run_reports_per_layer_metrics_and_checks_last(monkeypatch):
     assert list(res)[-1] == "checks"
 
 
+def _two_kernel_ctx(paths, names=("%checkpoint.6", "%gmm.2")):
+    """One 30 ms decode execution holding two Pallas calls, 20 ms under
+    the first name and 5 ms under the second, with the name paths given;
+    a 40 ms traced window of 64 rows at 1,000 keys."""
+    ms = 1_000_000
+    dev = "/device:TPU:0"
+    text = ' = bf16[8]{0} custom-call(), custom_call_target="tpu_custom_call"'
+    ops = [Event(names[0] + text, 0, 20 * ms, path=paths[0]),
+           Event(names[1] + text, 20 * ms, 25 * ms, path=paths[1])]
+    mods = [Event("jit__decode_step(1)", 0, 30 * ms)]
+    host = [Event(tracemod.TRACED, 0, 40 * ms)]
+    cell = harness.load_cell("smollm-135m.inhibitor.offline")
+    win = types.SimpleNamespace(
+        trace=Trace({dev: tracemod._in_modules(ops, mods)}, {dev: mods}, host),
+        trace_tally=harness.Tally(step_ctxs=[[1000] * 64]))
+    return harness.Ctx(cell=cell, config=cell.config,
+                       peaks=harness.load_peaks("TPU v5 lite"), setup_s=0.0,
+                       window=win)
+
+
+def test_attention_roofline_reads_only_the_scoped_kernel():
+    """Beside the paged kernel, an expert kernel in the decode program:
+    only the op whose name path holds the scope is attention's."""
+    from bench import flops
+
+    reader = harness.load_reader("decode_attn_roofline")
+    base = "jit(_decode_step)/while/body/closed_call/checkpoint/"
+    ctx = _two_kernel_ctx([base + reader.SCOPE + "/pallas_call",
+                           base + "pallas_call"])
+    least = flops.least_time(*flops.decode_attn_work(ctx.config, [1000] * 64),
+                             ctx.peaks)
+    assert reader.read(ctx) == pytest.approx(100 * least / 0.020)
+    swapped = _two_kernel_ctx([base + "pallas_call",
+                               base + reader.SCOPE + "/pallas_call"])
+    assert reader.read(swapped) == pytest.approx(100 * least / 0.005)
+
+
+def test_attention_roofline_raises_on_two_kernels_without_the_scope():
+    base = "jit(_decode_step)/while/body/closed_call/checkpoint/pallas_call"
+    ctx = _two_kernel_ctx([base, base])
+    with pytest.raises(harness.BenchError, match="paged_decode_attention"):
+        harness.load_reader("decode_attn_roofline").read(ctx)
+
+
+def test_attention_roofline_reads_one_unscoped_kernel_in_every_execution():
+    """A program that names no scope: one Pallas op per execution, as
+    another table-width bucket's program compiles it under another name,
+    is the paged kernel, as before the scope existed."""
+    ms = 1_000_000
+    dev = "/device:TPU:0"
+    text = ' = bf16[8]{0} custom-call(), custom_call_target="tpu_custom_call"'
+    ops = [Event("%checkpoint.6" + text, 0, 10 * ms),
+           Event("%checkpoint.6" + text, 10 * ms, 20 * ms),
+           Event("%checkpoint.13" + text, 30 * ms, 38 * ms)]
+    mods = [Event("jit__decode_step(1)", 0, 25 * ms),
+            Event("jit__decode_step(2)", 30 * ms, 40 * ms)]
+    tr = Trace({dev: tracemod._in_modules(ops, mods)}, {dev: mods},
+               [Event(tracemod.TRACED, 0, 50 * ms)])
+    evs = harness.load_reader("decode_attn_roofline").kernel_events(tr)
+    assert sum(e.end - e.start for e in evs) == 28 * ms and len(evs) == 3
+
+
 RECORDED = pathlib.Path(__file__).parent / "data" / "decode_trace.xplane.pb.gz"
 
 
@@ -123,6 +185,13 @@ def test_recorded_chip_trace_reduces_to_what_it_holds():
     kernel = harness.load_reader("decode_attn_roofline")
     ksecs, kn = tr.op_time(kernel.NAMES, kernel.PROGRAM)
     assert (ksecs, kn) == (pytest.approx(0.164755, abs=1e-6), 180)
+    # the op's name path is kept; this program (recorded before the scope)
+    # names none, so the reader takes its one Pallas call
+    evs = kernel.kernel_events(tr)
+    assert {e.path for e in evs} == {
+        "jit(_decode_step)/while/body/closed_call/checkpoint/pallas_call"}
+    assert not any(kernel.SCOPE in e.path for m in tr.ops.values() for e in m)
+    assert sum(e.end - e.start for e in evs) == pytest.approx(ksecs * 1e9)
     busy, span = tr.busy_window()
     assert secs <= busy <= span == pytest.approx(0.502829, abs=1e-6)
     top = tr.top_ops(3)
@@ -143,3 +212,43 @@ def test_short_names_keep_the_op_its_shape_and_the_call_target():
     assert tracemod.short("%while.2 = (s32[], f32[2]) while(%t)") == (
         "while.2 tuple")
     assert tracemod.short("jit__decode_step(1)") == "jit__decode_step(1)"
+
+
+def _pb(num, value):
+    """One protobuf field: a varint for an int, else length-delimited."""
+    def varint(n):
+        out = bytearray()
+        while True:
+            out.append((n & 0x7F) | (0x80 if n > 0x7F else 0))
+            n >>= 7
+            if not n:
+                return bytes(out)
+    if isinstance(value, int):
+        return varint(num << 3) + varint(value)
+    value = value.encode() if isinstance(value, str) else value
+    return varint(num << 3 | 2) + varint(len(value)) + value
+
+
+def test_op_paths_read_string_and_interned_name_paths():
+    """A device plane whose ops carry their ``tf_op`` stat as a string
+    (XStat field 5) and as a reference to an interned string (field 7);
+    a host plane's ops are not read."""
+    def stat_meta(i, name):
+        return _pb(5, _pb(1, i) + _pb(2, _pb(1, i) + _pb(2, name)))
+
+    def op(i, name, stat):
+        return _pb(4, _pb(1, i) + _pb(2, _pb(1, i) + _pb(2, name)
+                                     + _pb(5, stat)))
+
+    device = (_pb(2, "/device:TPU:0") + _pb(3, _pb(1, 9))
+              + stat_meta(7, "tf_op") + stat_meta(8, "jit(f)/pallas_call:")
+              + op(1, "%a = f32[2] custom-call()",
+                   _pb(1, 7) + _pb(5, "jit(f)/dot:"))
+              + op(2, "%b = f32[2] custom-call()", _pb(1, 7) + _pb(7, 8))
+              + op(3, "%c = f32[2] add()", _pb(1, 3) + _pb(5, "ignored")))
+    host = _pb(2, "/host:CPU") + stat_meta(7, "tf_op") + op(
+        1, "x", _pb(1, 7) + _pb(5, "y"))
+    space = _pb(1, device) + _pb(1, host)
+    assert tracemod.op_paths(space) == {"/device:TPU:0": {
+        "%a = f32[2] custom-call()": "jit(f)/dot",
+        "%b = f32[2] custom-call()": "jit(f)/pallas_call"}}

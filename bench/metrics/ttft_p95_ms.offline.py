@@ -13,5 +13,4 @@ from bench import stats
 
 def read(ctx):
     w = ctx.window
-    xs = stats.ttft_samples(w.records, w.t0, w.t1)
-    return stats.percentile(xs, 95) * 1e3 if xs else None
+    return stats.ttft_ms(w.records, w.t0, w.t1, 95)

@@ -55,6 +55,21 @@ def ttft_samples(records: Sequence[Record], t0: float,
     return out
 
 
+def ttft_ms(records: Sequence[Record], t0: float, t1: float,
+            q: float) -> Optional[float]:
+    """Percentile ``q`` of :func:`ttft_samples` in milliseconds, or None
+    where no request is due in the window."""
+    xs = ttft_samples(records, t0, t1)
+    return percentile(xs, q) * 1e3 if xs else None
+
+
+def waiting_at(records: Sequence[Record], t: float) -> int:
+    """Requests due by ``t`` that had no first token by ``t``: the queue
+    in front of prefill."""
+    return sum(1 for r in records
+               if r.due <= t and not (r.times and r.times[0] <= t))
+
+
 def itl_samples(records: Sequence[Record], t0: float,
                 t1: float) -> List[float]:
     """Seconds between consecutive tokens of one request, for every gap

@@ -10,9 +10,12 @@ swapped per seed, leftover requests cancelled between seeds; the window
 stays open until a full sample has finished) and prints,
 per seed, the compared number and, with ``--control``, the control's
 number at the same positions: the readings the limits are set from.
-``sweep`` offers a chat mix at each session rate and prints whether the
-backlog grew: it finds the knee.  ``trace`` keeps a short profiler trace
-and lists its planes, lines and busiest event names.
+``sweep`` offers a chat mix at each session rate, each rate to an engine
+built afresh, so every reading starts from a cold prefix cache
+as a benchmark run does, and prints how the queue of requests waiting
+for their first token moved through the window: it finds the knee.
+``trace`` keeps a short profiler trace and lists its planes, lines and
+busiest event names.
 """
 
 from __future__ import annotations
@@ -22,6 +25,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import pathlib  # noqa: E402
@@ -58,21 +62,17 @@ def _drain(eng, win) -> None:
 def _summary(win) -> dict:
     from bench import stats
 
-    t0, t1 = win.t0, win.t1
-    ttft = stats.ttft_samples(win.records, t0, t1)
-    itl = stats.itl_samples(win.records, t0, t1)
+    recs, t0, t1 = win.records, win.t0, win.t1
+    itl = stats.itl_samples(recs, t0, t1)
     half = (t0 + t1) / 2
-    early = stats.ttft_samples(win.records, t0, half)
-    late = stats.ttft_samples(win.records, half, t1)
-    due = stats.due_in(win.records, t0, t1)
+    due = stats.due_in(recs, t0, t1)
+    c0, c1 = win.counters0, win.counters1
     return {
-        "output_tok_s": stats.output_rate(win.records, t0, t1),
-        "ttft_p50_ms": stats.percentile(ttft, 50) * 1e3 if ttft else None,
-        "ttft_p95_ms": stats.percentile(ttft, 95) * 1e3 if ttft else None,
-        "ttft_p50_first_half_ms": (stats.percentile(early, 50) * 1e3
-                                   if early else None),
-        "ttft_p50_second_half_ms": (stats.percentile(late, 50) * 1e3
-                                    if late else None),
+        "output_tok_s": stats.output_rate(recs, t0, t1),
+        "ttft_p50_ms": stats.ttft_ms(recs, t0, t1, 50),
+        "ttft_p95_ms": stats.ttft_ms(recs, t0, t1, 95),
+        "ttft_p50_first_half_ms": stats.ttft_ms(recs, t0, half, 50),
+        "ttft_p50_second_half_ms": stats.ttft_ms(recs, half, t1, 50),
         "itl_p50_ms": stats.percentile(itl, 50) * 1e3 if itl else None,
         "itl_p95_ms": stats.percentile(itl, 95) * 1e3 if itl else None,
         "due": len(due),
@@ -80,6 +80,8 @@ def _summary(win) -> dict:
         "finished": sum(1 for r in due if r.finished is not None),
         "compiles_in_window": win.compiles,
         "cache_loads_in_window": win.cache_hits,
+        "prefix_hit_tokens": c1["prefix_hit_tokens"] - c0["prefix_hit_tokens"],
+        "prefill_tokens": c1["prefill_tokens"] - c0["prefill_tokens"],
     }
 
 
@@ -115,6 +117,30 @@ def calibrate(args) -> None:
                **_summary(win)})
 
 
+#: a queue whose fitted line rises by this many requests over the window
+#: is a backlog
+BACKLOG = 4.0
+
+
+def _queue(win) -> dict:
+    """The queue of requests due and still without a first token, read
+    once a second through the window, and its least-squares growth over
+    the window: a backlog grows, a sustained load only wanders."""
+    from bench import stats
+
+    n = int(win.t1 - win.t0)
+    ts = [win.t0 + i for i in range(n + 1)]
+    q = [stats.waiting_at(win.records, t) for t in ts]
+    mt, mq = sum(range(n + 1)) / (n + 1), sum(q) / (n + 1)
+    slope = (sum((i - mt) * (v - mq) for i, v in enumerate(q))
+             / sum((i - mt) ** 2 for i in range(n + 1)))
+    k = max(1, (n + 1) // 4)
+    return {"queue_first_quarter": sum(q[:k]) / k,
+            "queue_last_quarter": sum(q[-k:]) / k,
+            "queue_max": max(q), "queue_growth": slope * n,
+            "backlog": slope * n >= BACKLOG}
+
+
 def sweep(args) -> None:
     from bench import harness, traffic
 
@@ -123,14 +149,19 @@ def sweep(args) -> None:
     harness.device_info(cell.chips)
     c = cell.config
     compiles = harness._Compiles()
-    eng = harness.build_engine(c, args.seed)
     for rate in [float(r) for r in args.rates.split(",")]:
+        t0 = time.perf_counter()
+        eng = harness.build_engine(c, args.seed)
+        build_s = time.perf_counter() - t0
         mix = dict(cell.mix, rate_sessions_s=rate)
         gen = traffic.Traffic(mix, vocab=c["vocab_size"],
                               max_len=c["engine"]["max_len"], seed=args.seed)
         win = harness.drive(eng, gen, args.seconds, compiles=compiles)
         _drain(eng, win)
-        _emit({"rate_sessions_s": rate, **_summary(win)})
+        del eng
+        gc.collect()
+        _emit({"rate_sessions_s": rate, "build_s": build_s, **_summary(win),
+               **_queue(win)})
 
 
 def trace(args) -> None:

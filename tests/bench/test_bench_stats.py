@@ -54,3 +54,23 @@ def test_output_rate_counts_tokens_that_arrived_in_the_window():
 
 def test_due_in_is_half_open():
     assert [r.rid for r in stats.due_in(_records(), 10.0, 20.0)] == [1, 2, 3, 4]
+
+
+@pytest.mark.parametrize("q,want", [(50, 1.5e3),
+                                    (95, (2.0 + 0.85 * 3.0) * 1e3)])
+def test_ttft_ms_is_the_percentile_of_the_samples(q, want):
+    assert stats.ttft_ms(_records(), 10.0, 20.0, q) == pytest.approx(want)
+
+
+def test_ttft_ms_of_a_window_with_nothing_due_is_none():
+    assert stats.ttft_ms(_records()[:1], 10.0, 20.0, 95) is None
+
+
+@pytest.mark.parametrize("t,want", [(9.0, 1), (11.5, 0), (13.0, 1),
+                                    (16.0, 1), (19.5, 2), (30.0, 0)])
+def test_waiting_at_counts_due_requests_without_a_first_token(t, want):
+    # 0 waits 5-11, 1 waits 10-10.5, 2 waits 12-14, 3 waits 15-29,
+    # 4 waits 19-25, 5 waits 20-20.5
+    recs = _records()
+    recs[3].times = [29.0]
+    assert stats.waiting_at(recs, t) == want
